@@ -11,6 +11,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     which = set(sys.argv[1:]) or {"table1", "fig4", "fig5", "roofline"}
     print("name,us_per_call,derived")
 

@@ -68,7 +68,8 @@ class DispatchConfig:
     ``materialize_limit``: Ξ element count above which conv goes factored
         (None = ``core.conv.MATERIALIZE_LIMIT``).
     ``interpret``: force the Pallas interpreter off-TPU (tests/validation);
-        None = delegate the pallas path to reference off-TPU.
+        None = delegate the pallas path to reference off-TPU.  On a TPU
+        the kernels always compile with Mosaic; the interpreter never runs.
     """
 
     path: str = "auto"
@@ -157,8 +158,14 @@ def lookup(op: str, path: str) -> Callable[..., Any]:
     return impls.get(path, impls["reference"])
 
 
+def platform() -> str:
+    """The backend operator paths resolve against (``cpu``/``gpu``/``tpu``);
+    plans record it so one built elsewhere is never served here."""
+    return jax.default_backend()
+
+
 def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return platform() == "tpu"
 
 
 def choose_path(op: str, cfg: DispatchConfig, *,

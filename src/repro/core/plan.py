@@ -78,6 +78,8 @@ __all__ = [
     "apply_compiled",
     "apply_compiled_packed",
     "capture_compiled",
+    "jit_over",
+    "step_executor",
     "save_compiled_plan",
     "load_compiled_plan",
 ]
@@ -430,6 +432,8 @@ def build_plan(
                                probe_coef=probe_coef, phi=phi,
                                profile=profile, occupancy=occupancy)
     provenance = {
+        # the backend every operator's apply path was resolved on
+        "platform": dispatchlib.platform(),
         "bands_mode": ("auto" if autotuned
                        else "global" if bands is None
                        else "explicit"),
@@ -506,6 +510,14 @@ def apply_plan(plan: InferencePlan, coef: jnp.ndarray,
 #: default per-instance VMEM allowance for a fused block (of the ~16 MB/core
 #: budget; the rest is headroom for Mosaic's own spills and double buffering).
 VMEM_BUDGET = 12 << 20
+
+#: the rule that keeps the fused-block megakernel off a TPU, recorded in
+#: ``CompiledPlan.meta["rules"]`` of every plan it applies to.
+MEGAKERNEL_OFF_TPU = (
+    "megakernel off on tpu: Mosaic rejects kernels/fused_block.py "
+    "(_asm_tile lane-splitting reshape: unsupported shape cast; stride-2 "
+    "_conv_tile slice: only 2D gather) - fused steps run the packed-GEMM "
+    "twin fused_block_reference")
 
 
 def _r8(bands: int) -> int:
@@ -590,7 +602,9 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
     the activation between stages with elementwise lane slices/pads.
     Blocks whose operators are factored (never materialised Ξ) or — on
     the pallas path — whose VMEM estimate exceeds ``vmem_budget`` stay on
-    the per-layer walk.
+    the per-layer walk.  On a TPU the fused path resolves to ``"gemm"``
+    (the packed-GEMM twin, ``MEGAKERNEL_OFF_TPU``); ``meta["platform"]``
+    records the backend the paths were resolved on.
 
     ``image_size`` sizes the block grid the VMEM estimate assumes (the
     megakernel holds one image's whole feature map per grid instance);
@@ -606,8 +620,14 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
     path = dispatchlib.choose_path("fused_block", cfg)
     if path not in dispatchlib.available_paths("fused_block"):
         path = "reference"
+    platform = dispatchlib.platform()
+    rules: dict[str, str] = {}
+    if path == "pallas" and platform == "tpu":
+        path = "gemm"
+        rules["megakernel"] = MEGAKERNEL_OFF_TPU
     meta: dict[str, Any] = {"fused": [], "layers": {}, "vmem": {},
-                            "budget": int(vmem_budget), "path": path}
+                            "budget": int(vmem_budget), "path": path,
+                            "platform": platform, "rules": rules}
 
     st = plan.operators["stem"]
     cout0 = st.kernel.shape[0]
@@ -694,6 +714,43 @@ def _repack_width(h: jnp.ndarray, c: int, w_to: int) -> jnp.ndarray:
     return fit_width(h, c, w_to)
 
 
+def _stem_runs_gemm(stem: CompiledStem, path: str,
+                    cfg: dispatchlib.DispatchConfig,
+                    executor: str | None) -> bool:
+    """Whether a packed stem runs its packed GEMM (else the spatial
+    lowering): forced by ``executor="gemm"``, or resolved to a GEMM path."""
+    return stem.kind == "packed" and (
+        executor == "gemm" or path == "gemm"
+        or (path == "pallas" and not dispatchlib._pallas_delegates(cfg)))
+
+
+def step_executor(cp: CompiledPlan, step_name: str,
+                  executor: str | None = None) -> tuple[str, str]:
+    """``(kind, executor)`` of one schedule step as :func:`compiled_steps`
+    runs it.  ``kind`` is ``stem`` / ``fused`` / ``layers`` / ``head``;
+    ``executor`` is ``gemm`` (tile-packed GEMM over Ξ: a packed stem, or a
+    fused block's twin ``fused_block_reference``), ``pallas`` (the fused
+    megakernel), ``spatial`` (the spatial-resident XLA lowering),
+    ``layers`` (the per-layer walk; each conv runs its operator's own
+    ``path``) or ``xla`` (the head)."""
+    path = (cp.meta or {}).get("path", "reference")
+    if step_name == "stem":
+        if cp.stem.kind != "packed":
+            return "stem", "layers"
+        return "stem", ("gemm" if _stem_runs_gemm(cp.stem, path, cp.cfg,
+                                                  executor) else "spatial")
+    if step_name == "head":
+        return "head", "xla"
+    blk = next(b for b in cp.blocks if b.name == step_name)
+    if blk.kind != "fused":
+        return "layers", "layers"
+    if executor == "gemm" or blk.path == "gemm":
+        return "fused", "gemm"
+    if blk.path == "pallas" and not dispatchlib._pallas_delegates(cp.cfg):
+        return "fused", "pallas"
+    return "fused", "spatial"
+
+
 def _apply_stem(stem: CompiledStem, coef: jnp.ndarray, phi: int, path: str,
                 cfg: dispatchlib.DispatchConfig,
                 executor: str | None = None) -> jnp.ndarray:
@@ -702,8 +759,7 @@ def _apply_stem(stem: CompiledStem, coef: jnp.ndarray, phi: int, path: str,
 
     n, bh, bw = coef.shape[:3]
     if stem.kind == "packed":
-        if executor == "gemm" or (path == "pallas"
-                                  and not dispatchlib._pallas_delegates(cfg)):
+        if _stem_runs_gemm(stem, path, cfg, executor):
             h = coef[..., : stem.w_in].reshape(n, bh, bw,
                                                stem.cin * stem.w_in)
             h = tiling.packed_conv_apply(h, stem.conv)
@@ -803,9 +859,7 @@ def apply_compiled_packed(cp: CompiledPlan, packed: jnp.ndarray,
         raise ValueError(
             f"packed input has per-channel width {k / st.cin:g}, "
             f"stem expects w_in={st.w_in} (cin={st.cin})")
-    if st.kind == "packed" and (
-            executor == "gemm"
-            or (path == "pallas" and not dispatchlib._pallas_delegates(cfg))):
+    if _stem_runs_gemm(st, path, cfg, executor):
         from repro.kernels import tiling
 
         h = tiling.packed_conv_apply(packed, st.conv)
@@ -846,11 +900,15 @@ def capture_compiled(cp: CompiledPlan, shape, *, packed: bool = False,
     ``on_trace`` (no-arg callable) fires from inside the traced body —
     i.e. exactly once per compile — giving callers honest compile
     accounting without reaching into jax internals.
+
+    The schedule's arrays enter as arguments (:func:`jit_over`), not as
+    program constants; ``call.lower()`` lowers the captured entry for
+    inspection (``.compile().as_text()``).
     """
     shape = tuple(int(s) for s in shape)
     apply_fn = apply_compiled_packed if packed else apply_compiled
 
-    def fwd(x):
+    def fwd(cp, x):
         if tuple(x.shape) != shape:
             raise ValueError(
                 f"captured executable is pinned to shape {shape}, "
@@ -860,7 +918,7 @@ def capture_compiled(cp: CompiledPlan, shape, *, packed: bool = False,
             on_trace()
         return apply_fn(cp, x, executor=executor)
 
-    fn = jax.jit(fwd, donate_argnums=(0,) if donate else ())
+    fn = jit_over(cp, fwd, donate_argnums=(0,) if donate else ())
 
     def call(x):
         if not traced:
@@ -881,6 +939,35 @@ def capture_compiled(cp: CompiledPlan, shape, *, packed: bool = False,
 
     traced: list[bool] = []
     call.captured_shape = shape
+    call.lower = lambda: fn.lower(jax.ShapeDtypeStruct(shape, dtype))
+    return call
+
+
+def jit_over(tree: Any, fn, **jit_kw):
+    """``jax.jit`` of ``fn(tree, x)`` as a function of ``x`` alone, with
+    ``tree``'s arrays (a plan's or schedule's weights) passed to the
+    executable as arguments instead of being baked into the program as
+    constants — at published widths those constants are tens of MB that
+    would slow every compile and key the compile cache on the weights.
+    ``donate_argnums`` counts ``x`` as argument 0.  The returned callable
+    has ``.lower(x)``."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    idx = [i for i, leaf in enumerate(leaves)
+           if isinstance(leaf, (jax.Array, np.ndarray))]
+    arrays = [leaves[i] for i in idx]
+
+    def inner(x, arrs):
+        full = list(leaves)
+        for i, a in zip(idx, arrs):
+            full[i] = a
+        return fn(jax.tree_util.tree_unflatten(treedef, full), x)
+
+    jitted = jax.jit(inner, **jit_kw)
+
+    def call(x):
+        return jitted(x, arrays)
+
+    call.lower = lambda x: jitted.lower(x, arrays)
     return call
 
 
@@ -895,7 +982,7 @@ def _make_block_fn(blk: CompiledBlock, w_prev: int, phi: int,
         if blk.w_in != w_prev:
             h = _repack_width(h, blk.cin, blk.w_in)
         if blk.kind == "fused":
-            if executor == "gemm":
+            if executor == "gemm" or blk.path == "gemm":
                 h = fblib.fused_block_reference(h, blk.conv1, blk.asm_mid,
                                                 blk.conv2, blk.asm_out,
                                                 blk.proj)
@@ -969,10 +1056,7 @@ def compiled_steps(cp: CompiledPlan,
                 raise ValueError(
                     f"packed input has per-channel width {k / st.cin:g}, "
                     f"stem expects w_in={st.w_in} (cin={st.cin})")
-            if st.kind == "packed" and (
-                    executor == "gemm"
-                    or (path == "pallas"
-                        and not dispatchlib._pallas_delegates(cfg))):
+            if _stem_runs_gemm(st, path, cfg, executor):
                 from repro.kernels import tiling
 
                 h = tiling.packed_conv_apply(x, st.conv)

@@ -27,7 +27,8 @@ from repro.introspect.attribution import (BlockCost, block_costs,
 from repro.introspect.gridprof import profile_plan_grid
 from repro.introspect.report import render_text, validate_report, worst_ratio
 from repro.introspect.roofline import (PROFILES, HardwareProfile,
-                                       detect_backend, resolve_profile,
+                                       DEVICE_KINDS, detect_device_kind,
+                                       resolve_profile,
                                        roofline)
 
 __all__ = [
@@ -37,7 +38,8 @@ __all__ = [
     "StepProfile",
     "block_costs",
     "compiled_steps",
-    "detect_backend",
+    "DEVICE_KINDS",
+    "detect_device_kind",
     "predicted_vs_measured",
     "profile_plan_grid",
     "render_text",

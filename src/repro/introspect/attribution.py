@@ -95,29 +95,6 @@ class BlockCost:
         }
 
 
-def _step_executor(cp, step_name: str, executor: str | None,
-                   packed: bool) -> tuple[str, str]:
-    """(kind, executor label) for one schedule step."""
-    path = (cp.meta or {}).get("path", "reference")
-    if step_name == "stem":
-        st = cp.stem
-        if st.kind == "packed":
-            from repro.core import dispatch as dispatchlib
-
-            if executor == "gemm" or (
-                    path == "pallas"
-                    and not dispatchlib._pallas_delegates(cp.cfg)):
-                return "stem", "gemm"
-            return "stem", "spatial"
-        return "stem", "layers"
-    if step_name == "head":
-        return "head", "xla"
-    blk = next(b for b in cp.blocks if b.name == step_name)
-    if blk.kind != "fused":
-        return "layers", "layers"
-    return "fused", "gemm" if executor == "gemm" else blk.path
-
-
 def _step_bands(cp, step_name: str) -> tuple[int, int, dict, int]:
     """(bands_in, bands_out, per-layer bands, vmem estimate)."""
     if step_name == "stem":
@@ -174,7 +151,7 @@ def block_costs(cp, shape, *, executor: str | None = None,
     for name, fn in steps:
         hlo = _lower_hlo(fn, (aval,))
         cost = analyze_hlo(hlo, total_devices=total_devices)
-        kind, exec_label = _step_executor(cp, name, executor, packed)
+        kind, exec_label = planlib.step_executor(cp, name, executor)
         bands_in, bands_out, layer_bands, vmem = _step_bands(cp, name)
         roof = roofline(cost.flops, cost.bytes, cost.collective_bytes, hw)
         blocks.append(BlockCost(

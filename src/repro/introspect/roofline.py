@@ -4,7 +4,9 @@ This is the promotion of the roofline model that used to live (with
 hardcoded TPU v5e constants) in ``benchmarks/roofline.py``: a registry
 of :class:`HardwareProfile` peak numbers keyed by name, resolved from —
 in priority order — an explicit spec (CLI flag), the ``JPEG_HW_PROFILE``
-environment variable, a caller default, or the detected JAX backend.
+environment variable, a caller default, or the ``device_kind`` of the
+first JAX device.  A device kind missing from ``DEVICE_KINDS`` is an
+error, never a guessed profile.
 ``benchmarks/roofline.py`` is now a thin shim over this module.
 
 A profile spec is either a registry name (``tpu-v5e``, ``cpu``, ...) or
@@ -26,7 +28,8 @@ from dataclasses import dataclass
 __all__ = [
     "HardwareProfile",
     "PROFILES",
-    "detect_backend",
+    "DEVICE_KINDS",
+    "detect_device_kind",
     "resolve_profile",
     "roofline",
 ]
@@ -62,17 +65,22 @@ PROFILES: dict[str, HardwareProfile] = {
     "cpu": HardwareProfile("cpu", 100e9, 30e9, 10e9),
 }
 
-# jax.default_backend() platform → registry key
-_BACKEND_ALIAS = {"tpu": "tpu-v5e", "gpu": "gpu", "cpu": "cpu"}
+#: ``jax.Device.device_kind`` → registry key.  TPU v5e reports itself as
+#: "TPU v5 lite".
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v4": "tpu-v4",
+    "cpu": "cpu",
+}
 
 ENV_VAR = "JPEG_HW_PROFILE"
 
 
-def detect_backend() -> str:
-    """The active JAX platform name (``cpu`` / ``gpu`` / ``tpu``)."""
+def detect_device_kind() -> str:
+    """``device_kind`` of the first JAX device (e.g. ``TPU v5 lite``)."""
     import jax
 
-    return jax.default_backend()
+    return jax.devices()[0].device_kind
 
 
 def _parse_spec(spec: str) -> HardwareProfile:
@@ -97,8 +105,9 @@ def resolve_profile(spec: str | None = None, *,
     """Resolve the hardware profile to predict against.
 
     Priority: explicit ``spec`` (CLI) > ``JPEG_HW_PROFILE`` env var >
-    ``default`` registry name > the detected JAX backend.  ``spec`` and
-    the env var accept a registry name or a custom
+    ``default`` registry name > the detected ``device_kind``
+    (:data:`DEVICE_KINDS`; an unlisted kind raises ``ValueError``).
+    ``spec`` and the env var accept a registry name or a custom
     ``peak_flops,hbm_bw,link_bw`` triple.
     """
     if spec:
@@ -108,8 +117,13 @@ def resolve_profile(spec: str | None = None, *,
         return _parse_spec(env)
     if default is not None:
         return PROFILES[default]
-    backend = detect_backend()
-    return PROFILES[_BACKEND_ALIAS.get(backend, "cpu")]
+    kind = detect_device_kind()
+    if kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no hardware profile for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_KINDS)}); pass a profile name or a "
+            f"'peak_flops,hbm_bw,link_bw' triple")
+    return PROFILES[DEVICE_KINDS[kind]]
 
 
 def roofline(flops: float, bytes_: float, collective_bytes: float,

@@ -42,13 +42,14 @@ def _asm_relu_kernel(coef_ref, recon_phi_ref, recon_ref, recon_t_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("phi", "interpret"))
 def asm_relu_pallas(coef: jnp.ndarray, phi: int = 14, *,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """ASM ReLU over ``(N, nf)`` zigzag coefficients (orthonormal units).
 
     ``nf`` may be < 64 for band-truncated activations (paper §6 sparsity):
     the reconstruction operands shrink to ``(nf, 64)`` / ``(64, nf)`` so the
     dropped coefficients never enter the MXU.  ``interpret=True`` runs the
-    kernel body on CPU for validation; on TPU pass ``interpret=False``.
+    kernel body through the Pallas interpreter (off-TPU validation); on
+    TPU pass ``interpret=False``.
     """
     n, nf = coef.shape
     # Tile picked *from n* (balanced, sublane-aligned — kernels.tiling):

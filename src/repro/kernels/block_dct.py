@@ -68,7 +68,7 @@ def _inv_operator(quality: int | None) -> np.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("quality", "interpret"))
 def block_dct_pallas(blocks: jnp.ndarray, *, quality: int | None = None,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool) -> jnp.ndarray:
     """(N, 8, 8) pixel blocks -> (N, 64) zigzag coefficients."""
     n = blocks.shape[0]
     return _run(blocks.reshape(n, 64), _fwd_operator(quality), interpret)
@@ -76,7 +76,7 @@ def block_dct_pallas(blocks: jnp.ndarray, *, quality: int | None = None,
 
 @functools.partial(jax.jit, static_argnames=("quality", "interpret"))
 def block_idct_pallas(coef: jnp.ndarray, *, quality: int | None = None,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool) -> jnp.ndarray:
     """(N, 64) zigzag coefficients -> (N, 8, 8) pixel blocks."""
     out = _run(coef, _inv_operator(quality), interpret)
     return out.reshape(coef.shape[0], 8, 8)

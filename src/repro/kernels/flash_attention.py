@@ -71,7 +71,7 @@ def _make_kernel(causal: bool, window: int | None, qt: int, kt: int,
                    static_argnames=("causal", "window", "interpret"))
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            causal: bool = True, window: int | None = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """``q``: (B, S, H, hd); ``k``/``v``: (B, T, KVH, hd) -> (B, S, H, hd)."""
     b, s, h, hd = q.shape
     t, kvh = k.shape[1], k.shape[2]

@@ -29,10 +29,15 @@ VMEM budget per block tile (float32 bytes, per grid instance):
 
 ``core.plan.compile_plan`` evaluates this sum against its ``vmem_budget``
 (default 12 MB of the ~16 MB/core budget) and falls back to per-layer
-execution for blocks that do not fit.  Like the other kernels in this
-package the body is interpreter-validated on CPU (tests force
-``interpret=True``); Mosaic compilation on TPU is tracked by the ROADMAP
-"TPU non-interpret CI" item.
+execution for blocks that do not fit.
+
+The body is interpreter-validated only: Mosaic (the TPU compiler) rejects
+two of its in-kernel ops — the lane-splitting reshape in ``_asm_tile``
+(``infer-vector-layout: unsupported shape cast``) and the strided slice
+of a stride-2 ``_conv_tile`` (``Only 2D gather is supported``).  So
+``core.plan.compile_plan`` never schedules it on a TPU
+(``MEGAKERNEL_OFF_TPU``); the fused step there runs the operand-identical
+XLA twin :func:`fused_block_reference`.
 """
 from __future__ import annotations
 
@@ -109,7 +114,7 @@ def _make_kernel(conv1: PackedConv, asm_mid: PackedAsm, conv2: PackedConv,
 def fused_block_pallas(x: jnp.ndarray, conv1: PackedConv, asm_mid: PackedAsm,
                        conv2: PackedConv, asm_out: PackedAsm,
                        proj: PackedConv | None = None, *,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """Run one residual block fused; ``x`` is ``(N, bh, bw, Cin·w)``.
 
     Each operand is applied at its own packed band width; the activation

@@ -9,7 +9,10 @@ one instance computes one output block-row tile:
 The input row is passed once per ``dy`` offset (same buffer, shifted
 index map — overlapping windows are not expressible with one BlockSpec);
 ``ci`` is the accumulation grid dim (output block constant across it, so
-revisiting is legal).  Channel tiles keep the Ξ slices inside VMEM:
+revisiting is legal).  A strided conv reads its columns through the
+wrapper's ``stride`` column phases (``x[..., p::stride, :]``), so the
+kernel body only takes unit-stride sublane slices — Mosaic lowers no
+strided vector slice.  Channel tiles keep the Ξ slices inside VMEM:
 (ndx, 256, 256) fp32 per dy ≈ 0.8 MB.
 
 This kernel is why the paper's "sparse einsum" complaint (§6) does not
@@ -32,10 +35,12 @@ CH_TILE = 256
 
 
 def _make_kernel(ndy: int, ndx: int, stride: int, bw_out: int):
+    n_in = ndy * stride
+
     def kernel(*refs):
-        in_refs = refs[:ndy]
-        xi_refs = refs[ndy: 2 * ndy]
-        out_ref = refs[2 * ndy]
+        in_refs = refs[:n_in]           # [dy * stride + phase]
+        xi_refs = refs[n_in: n_in + ndy]
+        out_ref = refs[n_in + ndy]
         ci = pl.program_id(3)
 
         @pl.when(ci == 0)
@@ -44,10 +49,13 @@ def _make_kernel(ndy: int, ndx: int, stride: int, bw_out: int):
 
         acc = jnp.zeros(out_ref.shape[2:], jnp.float32)
         for dy in range(ndy):
-            row = in_refs[dy][0, 0]  # (bw_pad, ci_tile)
             xi_dy = xi_refs[dy]      # (1, ndx, ci_tile, co_tile)
             for dx in range(ndx):
-                sl = row[dx: dx + stride * bw_out: stride]  # (bw_out, ci_tile)
+                # padded column dx + stride·j lives in phase dx % stride
+                # at position dx // stride + j
+                row = in_refs[dy * stride + dx % stride][0, 0]
+                q = dx // stride
+                sl = row[q: q + bw_out]  # (bw_out, ci_tile)
                 acc = acc + jnp.dot(sl, xi_dy[0, dx],
                                     preferred_element_type=jnp.float32)
         out_ref[0, 0] = (out_ref[0, 0] + acc.astype(out_ref.dtype))
@@ -57,13 +65,15 @@ def _make_kernel(ndy: int, ndx: int, stride: int, bw_out: int):
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret"))
 def jpeg_conv_pallas(coef: jnp.ndarray, xi: jnp.ndarray, stride: int = 1, *,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool) -> jnp.ndarray:
     """Apply an exploded operator.
 
     ``coef``: (N, bh, bw, Cin, nf); ``xi``: (ndy, ndx, Cin, nf, Cout, nf').
     Returns (N, bh/stride, bw/stride, Cout, nf').  Matches
     ``core.conv.apply_exploded`` exactly (tests sweep shapes); band-truncated
     operators (``nf = nf' = bands < 64``) shrink the matmuls accordingly.
+    ``interpret`` runs the body through the Pallas interpreter (off-TPU
+    validation); on TPU pass False.
     """
     ndy, ndx = xi.shape[0], xi.shape[1]
     nf_in, cout, nf_out = xi.shape[3], xi.shape[4], xi.shape[5]
@@ -93,15 +103,21 @@ def jpeg_conv_pallas(coef: jnp.ndarray, xi: jnp.ndarray, stride: int = 1, *,
         p = round_up(co_full, tco) - co_full
         w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, p)))
     co_pad = w.shape[-1]
-    bw_pad = x.shape[2]
+    # column phases: phase p holds padded columns p, p+s, p+2s, ...
+    bw_pad = round_up(x.shape[2], stride)
+    if bw_pad != x.shape[2]:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, bw_pad - x.shape[2]), (0, 0)))
+    phases = [x[:, :, p::stride] for p in range(stride)]
+    bw_ph = bw_pad // stride
 
     grid = (n, bh_out, co_pad // tco, ci_full // tci)
     in_specs = []
     for dy in range(ndy):
-        in_specs.append(pl.BlockSpec(
-            (1, 1, bw_pad, tci),
-            functools.partial(
-                lambda b, i, co, ci, dy=dy: (b, stride * i + dy, 0, ci))))
+        for _p in range(stride):
+            in_specs.append(pl.BlockSpec(
+                (1, 1, bw_ph, tci),
+                functools.partial(
+                    lambda b, i, co, ci, dy=dy: (b, stride * i + dy, 0, ci))))
     for dy in range(ndy):
         in_specs.append(pl.BlockSpec(
             (1, ndx, tci, tco),
@@ -117,6 +133,6 @@ def jpeg_conv_pallas(coef: jnp.ndarray, xi: jnp.ndarray, stride: int = 1, *,
         out_shape=jax.ShapeDtypeStruct((n, bh_out, bw_out, co_pad),
                                        coef.dtype),
         interpret=interpret,
-    )(*([x] * ndy + [w] * ndy))
+    )(*(phases * ndy + [w] * ndy))
     out = out[..., : cout * nf_out]
     return out.reshape(n, bh_out, bw_out, cout, nf_out)

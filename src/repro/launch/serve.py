@@ -74,7 +74,8 @@ from repro.configs.base import get_config, reduced_config
 from repro.core import dispatch as dispatchlib
 from repro.models.registry import build_model
 
-__all__ = ["main", "serve_lm", "serve_jpeg_resnet", "prepare_plan",
+__all__ = ["main", "build_parser", "serve_lm", "serve_jpeg_resnet",
+           "prepare_plan",
            "prepare_ladder", "parse_tiers", "jpeg_byte_requests",
            "run_metadata"]
 
@@ -228,6 +229,11 @@ def prepare_plan(args, cfg, dcfg):
     subdirectory: built+saved alongside a fresh plan (and on explicit
     ``--compiled``); ``--no-compiled`` serves the per-layer plan walk, and
     the default uses a compiled schedule whenever the directory holds one.
+
+    Plans and schedules record the backend their apply paths were
+    resolved on; one resolved on another backend (a CPU-built plan
+    restored on a TPU would run its reference executors there) is
+    rebuilt, as is one that predates the record.
     """
     from repro.core import plan as planlib
     from repro.core import resnet as R
@@ -238,6 +244,7 @@ def prepare_plan(args, cfg, dcfg):
     want_compiled = getattr(args, "compiled", None)
     from_bytes = getattr(args, "ingest", "coefficients") == "bytes"
     plan_dir = args.plan_dir or os.path.join("plans", cfg.name)
+    platform = dispatchlib.platform()
     plan, built = None, False
     try:
         plan = planlib.load_plan(plan_dir)
@@ -245,6 +252,7 @@ def prepare_plan(args, cfg, dcfg):
         plan = None
     if plan is not None and (
             plan.spec != spec
+            or (plan.provenance or {}).get("platform") != platform
             or (args.dispatch is not None and plan.cfg.path != args.dispatch)
             or (args.bands is not None
                 and set(plan.bands.values()) != {args.bands})
@@ -290,7 +298,9 @@ def prepare_plan(args, cfg, dcfg):
             try:
                 compiled = planlib.load_compiled_plan(compiled_dir)
                 had_artifact = True
-                if compiled.spec != plan.spec or compiled.bands != plan.bands:
+                if (compiled.spec != plan.spec
+                        or compiled.bands != plan.bands
+                        or (compiled.meta or {}).get("platform") != platform):
                     compiled = None  # stale schedule for a different plan
             except FileNotFoundError:
                 pass
@@ -305,7 +315,7 @@ def prepare_plan(args, cfg, dcfg):
                 compiled_dir)
             compiled = planlib.load_compiled_plan(compiled_dir)
     info = {"dir": plan_dir, "built": built, "bands": plan.bands,
-            "path": plan.cfg.path, "fused_bn": True,
+            "path": plan.cfg.path, "platform": platform, "fused_bn": True,
             "compiled": compiled is not None}
     if compiled is not None:
         meta = compiled.meta or {}
@@ -458,10 +468,17 @@ def _submit_retry(sched, serving, payload, kind, deadline_s,
         time.sleep(0.01)      # queue full — admission backpressure
 
 
-def _serve_jpeg_qos(args, cfg, plan, plan_info) -> dict:
+def _serve_jpeg_qos(args, cfg, plan, plan_info, record=None) -> dict:
     """Serve through the band-elastic runtime: saturating burst of
     single-image requests → admission control, per-batch tier selection,
     degradation under overload, recovery on drain.
+
+    Outside ``--chaos`` a request that fails in the executor or in ingest
+    infrastructure, or a run that completes nothing, raises after the
+    report is emitted (a non-zero exit).  ``record`` (a dict, for
+    in-process callers) receives ``served``, a ``(payload, tier name,
+    logits)`` tuple per completed request, and ``grid``, the
+    :class:`~repro.serving.grid.PlanGrid` whose cells served them.
 
     ``--chaos`` turns the burst into a fault drill: a deterministic
     fraction of requests get corrupted bytes, one ingest worker is
@@ -522,7 +539,9 @@ def _serve_jpeg_qos(args, cfg, plan, plan_info) -> dict:
         grid=(n_blocks, n_blocks), channels=cfg.in_channels,
         breaker=breaker_policy, faults=faults, tracer=tracer)
     with obs, sched:
+        t_warm = time.time()
         sched.warmup(kinds=(kind,))
+        warmup_s = time.time() - t_warm
         gs = sched.grid_engine.summary()
         print(f"[serve] plan grid: {gs['distinct_columns']} tier columns x "
               f"buckets {gs['buckets']} = {gs['cells']} captured cells "
@@ -611,19 +630,28 @@ def _serve_jpeg_qos(args, cfg, plan, plan_info) -> dict:
         else:
             coefs = np.stack([np.asarray(r.payload) for r in probe])
         ref = np.asarray(planlib.apply_plan(plan, jnp.asarray(coefs)))
-        served = np.stack([np.asarray(r.result()) for r in probe])
-        agree = float(np.mean(ref.argmax(-1) == served.argmax(-1)))
+        got = np.stack([np.asarray(r.result()) for r in probe])
+        agree = float(np.mean(ref.argmax(-1) == got.argmax(-1)))
 
     qos_report = metrics.report()
     qos_report["grid"] = gs
+    qos_report["warmup_s"] = warmup_s
+    qos_report["executor"] = sched.executor
     qos_report["tiers"] = [
         {"name": t.name, "cap": t.cap,
-         "bands": sorted(set(t.bands.values()))} for t in ladder.tiers]
+         "bands": sorted(set(t.bands.values())),
+         "rules": dict((t.compiled.meta or {}).get("rules", {})),
+         "steps": _step_labels(t.compiled, sched.executor)}
+        for t in ladder.tiers]
     qos_report["top1_agree_top_tier"] = agree
     served_n = len(requests)
     completed = sum(1 for _, r in requests if r.tier is not None)
+    if record is not None:
+        record["served"] = [(r.payload, r.tier, r.result())
+                            for _, r in requests if r.tier is not None]
+        record["grid"] = sched.grid_engine
     out = {"arch": cfg.name, "images": served_n, "wall_s": wall,
-           "images_per_s": served_n / max(wall, 1e-9),
+           "images_per_s": completed / max(wall, 1e-9),
            "completed": completed, "rejected": total - served_n,
            "dispatch": plan.cfg.path, "ingest": kind,
            "latency_ms": qos_report["latency_ms"],
@@ -665,6 +693,34 @@ def _serve_jpeg_qos(args, cfg, plan, plan_info) -> dict:
                 if i not in faults.corrupted and r.tier is not None),
         }
     _emit_report(args, out)
+    if not chaos:
+        broken = [r.error() for _, r in requests
+                  if isinstance(r.error(), serving.RequestFailed)
+                  and r.error().stage in ("executor", "ingest")]
+        if broken:
+            raise RuntimeError(
+                f"{len(broken)} request(s) failed in the service") \
+                from broken[0]
+        if total and not completed:
+            raise RuntimeError("no request completed")
+    return out
+
+
+def _step_labels(cp, executor) -> dict[str, str]:
+    """Report label per schedule step: its executor
+    (:func:`core.plan.step_executor`), and on a per-layer walk each conv's
+    dispatch path."""
+    from repro.core import plan as planlib
+
+    out = {}
+    for name in ["stem", *(b.name for b in cp.blocks)]:
+        label = planlib.step_executor(cp, name, executor)[1]
+        if label == "layers":
+            ops = ({"stem": cp.stem.op} if name == "stem" else
+                   next(b for b in cp.blocks if b.name == name).ops)
+            label += " (" + ", ".join(f"{slot}:{op.path}" for slot, op
+                                      in sorted(ops.items())) + ")"
+        out[name] = label
     return out
 
 
@@ -676,7 +732,9 @@ def _emit_report(args, out: dict) -> None:
             json.dump(out, f, indent=2)
 
 
-def serve_jpeg_resnet(args) -> dict:
+def serve_jpeg_resnet(args, *, record=None) -> dict:
+    """Serve jpeg-resnet per ``args`` (the CLI namespace); ``record`` is
+    passed to the ``--qos`` runtime (see :func:`_serve_jpeg_qos`)."""
     from repro.core import plan as planlib
     from repro.data import jpeg_iterator
 
@@ -697,7 +755,7 @@ def serve_jpeg_resnet(args) -> dict:
     if getattr(args, "qos", False):
         # thin-CLI handoff: the band-elastic runtime owns batching, tier
         # selection, deadlines, and metrics from here on
-        return _serve_jpeg_qos(args, cfg, plan, plan_info)
+        return _serve_jpeg_qos(args, cfg, plan, plan_info, record=record)
     if getattr(args, "trace_out", None) or getattr(args, "metrics_out",
                                                    None) \
             or getattr(args, "profile_grid", False):
@@ -842,7 +900,9 @@ def serve_jpeg_resnet(args) -> dict:
     return out
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's argument parser (``main`` parses ``sys.argv`` with
+    it; in-process callers parse their own argv)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -963,11 +1023,17 @@ def main() -> None:
                          "dir holds a compiled schedule (one is compiled "
                          "and saved whenever the plan itself is built); "
                          "--no-compiled forces the per-layer plan walk")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> dict:
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.arch == "jpeg-resnet":
-        serve_jpeg_resnet(args)
-    else:
-        serve_lm(args)
+        return serve_jpeg_resnet(args)
+    return serve_lm(args)
 
 
 if __name__ == "__main__":

@@ -1,55 +1,25 @@
-"""Version-compatible jax imports.
+"""The jax spellings this repo uses for ``shard_map``, meshes and axis sizes.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax.shard_map`` (and its replication check was renamed
-``check_rep`` -> ``check_vma``).  Call sites in this repo use the modern
-spelling; this shim makes it work back to jax 0.4.x.
+One import site for the three, so call sites stay short; the installed jax
+(0.9) provides all of them directly.
 """
 from __future__ import annotations
 
-import functools
+import jax
+from jax import shard_map
 
-try:  # jax >= 0.5: top-level export, ``check_vma`` keyword
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    shard_map = _shard_map
-except ImportError:  # jax 0.4.x: experimental module, ``check_rep`` keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @functools.wraps(_shard_map)
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  **kwargs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kwargs)
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    jax >= 0.5 grew ``axis_types`` (and made Explicit sharding opt-in);
-    jax 0.4.x meshes are implicitly Auto, so the argument is simply
-    omitted there.
-    """
-    import jax
-
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
-    return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+    """``jax.make_mesh`` with every axis typed ``Auto`` (the implicit
+    sharding-propagation mode the sharding rules are written for)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def axis_size(axis) -> int:
-    """Size of a named mesh axis inside shard_map'd code.
-
-    ``jax.lax.axis_size`` only exists on newer jax; ``psum(1, axis)`` is
-    the classic spelling (constant-folds to the axis size).
-    """
-    import jax
-
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return int(jax.lax.psum(1, axis))
+    """Size of a named mesh axis inside shard_map'd code."""
+    return jax.lax.axis_size(axis)
 
 
 __all__ = ["shard_map", "make_mesh", "axis_size"]

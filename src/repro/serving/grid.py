@@ -12,9 +12,9 @@ cell, all captured at warmup:
   (:func:`bucket_for` — 1→1, 3→4, 9→16, 17→24 …), so low-occupancy
   traffic stops paying ``max_batch``-wide GEMMs;
 * **tiers** are the ladder's band tiers; every cell in a tier column
-  closes over the *same* prefix-sliced Ξ buffers (closed-over jax arrays
-  lower to jaxpr consts shared across executables), so device memory
-  stays O(one ladder) no matter how many buckets are captured;
+  passes the *same* prefix-sliced Ξ buffers to its executable as
+  arguments (``core.plan.jit_over``), so device memory stays O(one
+  ladder) no matter how many buckets are captured;
 * each cell is a **static-shape, donated** entry point
   (``core.plan.capture_compiled``): the input device buffer is donated
   to the executable and the host side stages rows into a reusable
@@ -203,6 +203,11 @@ class GridCell:
         host = self._pool.get(self._shape)
         host[:] = 0.0
         self._fn(jnp.array(host)).block_until_ready()
+
+    def lower(self):
+        """Lowering of this cell's own captured entry
+        (``.compile().as_text()`` is the program its dispatches run)."""
+        return self._fn.lower()
 
     def time_wall(self, *, iters: int = 3) -> float:
         """Median wall (seconds) of the captured executable on a zero

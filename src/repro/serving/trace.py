@@ -381,21 +381,16 @@ def jax_profile(trace_dir: str | None):
 
     The device profile covers the same wall-clock window as the flight
     recorder, so host-side spans and device-side HLO timings can be
-    correlated.  ``None`` is a no-op (the common case); an unavailable
-    profiler backend degrades to a no-op with a warning rather than
-    failing the serve run.
+    correlated.  ``None`` is a no-op (the common case); a requested
+    profile that cannot start raises — a run asked to trace the device
+    never quietly runs untraced.
     """
     if not trace_dir:
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(trace_dir)
-    except Exception as e:  # profiler backend unavailable — don't kill serving
-        print(f"[trace] jax.profiler unavailable ({e}); continuing without")
-        yield
-        return
+    jax.profiler.start_trace(trace_dir)
     try:
         yield
     finally:

@@ -93,7 +93,7 @@ def test_encoder_rejects_out_of_range():
         enc.encode_baseline([c], [q])
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000), st.sampled_from([(2, 2), (2, 1), (1, 2)]))
 def test_upsample_matches_spatial_replication(seed, f):
     """Coefficient-domain chroma upsampling == decode, replicate pixels,

@@ -88,6 +88,61 @@ def test_compiled_matches_plan_pallas_interpret(setup, bands):
     assert (got.argmax(-1) == ref.argmax(-1)).all()
 
 
+def test_tpu_rule_schedules_packed_gemm_twin(setup, monkeypatch):
+    """On a TPU compile_plan never schedules the megakernel (Mosaic refuses
+    it): the rule is recorded in meta, fused steps resolve to the packed
+    GEMM twin, and that schedule still matches the plan walk."""
+    spec, params, state, coef = setup
+    plan = PL.build_plan(params, state, spec,
+                         dispatch=DSP.DispatchConfig(bands=48))
+    ref = np.asarray(PL.apply_plan(plan, coef))
+    monkeypatch.setattr(DSP, "platform", lambda: "tpu")
+    cp = PL.compile_plan(plan)
+    assert cp.meta["platform"] == "tpu"
+    assert cp.meta["rules"] == {"megakernel": PL.MEGAKERNEL_OFF_TPU}
+    assert cp.meta["path"] == "gemm"
+    assert cp.meta["fused"] == ["s0b0", "s1b0"]
+    assert all(b.path == "gemm" for b in cp.blocks)
+    assert {PL.step_executor(cp, b.name)[1] for b in cp.blocks} \
+        == {"gemm"}
+    monkeypatch.undo()
+    got = np.asarray(PL.apply_compiled(cp, coef))
+    np.testing.assert_allclose(got, ref, atol=2e-4)
+    assert (got.argmax(-1) == ref.argmax(-1)).all()
+
+
+@pytest.mark.parametrize("path,interpret,executor,want", [
+    ("pallas", True, None, "pallas"),
+    ("pallas", None, None, "spatial"),
+    ("reference", None, None, "spatial"),
+    ("reference", None, "gemm", "gemm"),
+])
+def test_step_executor_names_what_runs(setup, monkeypatch, path, interpret,
+                                       executor, want):
+    """plan.step_executor (what introspection and the serve report name)
+    is the body each fused step actually runs — including a pallas path
+    that delegates off-TPU."""
+    import repro.kernels.fused_block as FB
+    import repro.kernels.ops as KO
+
+    spec, params, state, coef = setup
+    cfg = DSP.DispatchConfig(path=path, bands=48, interpret=interpret)
+    cp = PL.compile_plan(PL.build_plan(params, state, spec, dispatch=cfg))
+    ran = []
+    for mod, name, label in ((FB, "fused_block_reference", "gemm"),
+                             (FB, "fused_block_spatial", "spatial"),
+                             (KO, "fused_block", "pallas")):
+        def spy(*a, _real=getattr(mod, name), _label=label, **k):
+            ran.append(_label)
+            return _real(*a, **k)
+
+        monkeypatch.setattr(mod, name, spy)
+    PL.apply_compiled(cp, coef, executor=executor)
+    assert set(ran) == {want}
+    assert {PL.step_executor(cp, b.name, executor) for b in cp.blocks} \
+        == {("fused", want)}
+
+
 def test_megakernel_matches_packed_xla_twin(setup):
     """fused_block_pallas ≡ fused_block_reference on arbitrary packed
     inputs (both shortcut kinds), not only band-limited ones."""

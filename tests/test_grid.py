@@ -125,6 +125,28 @@ def test_cell_matches_unpadded_compiled_plan(setup):
             np.testing.assert_allclose(got[:n], want, atol=1e-5)
 
 
+def test_cell_lower_is_the_captured_entry(setup):
+    """GridCell.lower lowers the cell's own captured entry: pinned to the
+    bucket's shape, the schedule's arrays passed as arguments rather than
+    baked in, and the bucket's logits out; lowering serves nothing."""
+    spec, coef, plan = setup
+    g = SV.PlanGrid(SV.build_ladder(plan, caps=(None,)), batch=4,
+                    grid=tuple(coef.shape[1:3]), channels=int(coef.shape[3]),
+                    executor=EXECUTOR)
+    col = g.columns[0]
+    col.coef_fn(np.asarray(coef[:3], np.float32))
+    cell = col.cells[("coefficients", 4)]
+    lowered = cell.lower()
+    (x_info, arrays_info), _ = lowered.args_info
+    assert x_info.shape == (4, *coef.shape[1:])
+    assert len(arrays_info) == len(
+        [a for a in jax.tree_util.tree_leaves(col.compiled)
+         if isinstance(a, (jax.Array, np.ndarray))])
+    assert lowered.out_info.shape == (4, spec.num_classes)
+    assert "ENTRY" in lowered.compile().as_text()
+    assert cell.hits == 1
+
+
 def test_grid_manifest_roundtrip_bit_exact(setup, tmp_path):
     """Ladder manifest persists the capture buckets; a grid rebuilt from
     the restored ladder serves bit-identical logits per cell."""

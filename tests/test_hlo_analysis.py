@@ -137,10 +137,7 @@ ws = jax.ShapeDtypeStruct((6, 128, 128), jnp.float32)
 with mesh:
     c = jax.jit(unroll, in_shardings=(NamedSharding(mesh, P("data", None)),
                                       NamedSharding(mesh, P(None, None, "model")))).lower(xs, ws).compile()
-ca = c.cost_analysis()
-if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-    ca = ca[0]
-xla = ca["flops"]
+xla = c.cost_analysis()["flops"]
 mine = analyze_hlo(c.as_text(), 8).flops
 rel = abs(mine - xla) / xla
 print("xla", xla, "mine", mine, "rel", rel)

@@ -136,6 +136,21 @@ def test_resolve_profile_priority(monkeypatch):
         introspect.resolve_profile("not-a-profile")
 
 
+def test_resolve_profile_keys_on_device_kind(monkeypatch):
+    """Detection maps device_kind through DEVICE_KINDS; an unknown kind
+    raises instead of borrowing another device's peaks."""
+    import importlib
+
+    # the package re-exports a function named roofline; take the module
+    RL = importlib.import_module("repro.introspect.roofline")
+    monkeypatch.delenv("JPEG_HW_PROFILE", raising=False)
+    monkeypatch.setattr(RL, "detect_device_kind", lambda: "TPU v5 lite")
+    assert introspect.resolve_profile().name == "tpu-v5e"
+    monkeypatch.setattr(RL, "detect_device_kind", lambda: "TPU v9 future")
+    with pytest.raises(ValueError, match="TPU v9 future"):
+        introspect.resolve_profile()
+
+
 # --------------------------------------------------------------------------
 # Measured attribution
 # --------------------------------------------------------------------------

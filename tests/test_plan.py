@@ -230,3 +230,39 @@ def test_plan_restore_tree_generic(tmp_path):
     np.testing.assert_array_equal(vals[1][1], arrays["b"])
     with pytest.raises(FileNotFoundError):
         m.restore_tree(99)
+
+
+def test_prepare_plan_rebuilds_plan_resolved_on_another_platform(tmp_path):
+    """A restored plan or compiled schedule whose apply paths were resolved
+    on another backend (here: recorded as a TPU's) is rebuilt, never
+    served; one from this backend is restored."""
+    import argparse
+
+    from repro.configs.base import reduced_config
+    from repro.launch import serve as S
+
+    ns = argparse.Namespace(seed=0, dispatch=None, bands=None,
+                            plan_dir=str(tmp_path), autotune_bands=False,
+                            compiled=None, ingest="coefficients")
+    cfg = reduced_config("jpeg-resnet")
+    dcfg = DSP.DispatchConfig()
+    here = DSP.platform()
+    plan, cp, info = S.prepare_plan(ns, cfg, dcfg)
+    assert info["built"] and info["platform"] == here
+    assert plan.provenance["platform"] == here
+    assert cp.meta["platform"] == here
+    assert not S.prepare_plan(ns, cfg, dcfg)[2]["built"]
+
+    # the schedule alone claims another platform: recompiled, plan kept
+    PL.save_compiled_plan(cp._replace(meta=dict(cp.meta, platform="tpu")),
+                          str(tmp_path / "compiled"))
+    _, cp2, info2 = S.prepare_plan(ns, cfg, dcfg)
+    assert not info2["built"] and cp2.meta["platform"] == here
+
+    # the plan claims another platform: rebuilt from the seed
+    PL.save_plan(plan._replace(provenance=dict(plan.provenance,
+                                               platform="tpu")),
+                 str(tmp_path))
+    assert PL.load_plan(str(tmp_path)).provenance["platform"] == "tpu"
+    plan3, _, info3 = S.prepare_plan(ns, cfg, dcfg)
+    assert info3["built"] and plan3.provenance["platform"] == here

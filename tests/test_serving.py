@@ -487,3 +487,62 @@ def test_metrics_percentiles_shape():
     assert rep["p50_ms"] == pytest.approx(25.0, abs=1.0)
     assert rep["p99_ms"] <= rep["max_ms"] == pytest.approx(100.0)
     assert SV.percentiles([]) == {"n": 0}
+
+
+def test_qos_serve_raises_when_executor_fails(tmp_path, monkeypatch):
+    """Outside --chaos, a request the executor fails makes the QoS serve
+    raise (non-zero exit) after its report; images_per_s counts only
+    completed images."""
+    import json
+
+    from repro.launch import serve as S
+
+    def broken(self, *a, **k):
+        raise RuntimeError("injected executor fault")
+
+    monkeypatch.setattr(SV.BandElasticScheduler, "_execute", broken)
+    report = tmp_path / "report.json"
+    args = S.build_parser().parse_args(
+        ["--arch", "jpeg-resnet", "--reduced", "--qos", "--batch", "2",
+         "--requests", "2", "--tiers", "auto", "--batch-buckets", "fixed",
+         "--plan-dir", str(tmp_path / "plan"), "--report-out",
+         str(report)])
+    with pytest.raises(RuntimeError, match="failed in the service"):
+        S.serve_jpeg_resnet(args)
+    out = json.loads(report.read_text())
+    assert out["completed"] == 0 and out["images_per_s"] == 0.0
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says (setting no directory themselves), else at the checkout's fixed
+    ``.jax_cache/``, and lower JAX's persist threshold unless
+    JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS is set; importing the
+    module sets nothing."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as CC
+
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    root = Path(__file__).resolve().parents[1]
+    try:
+        assert CC.enable_compile_cache() == "/cache/from/env"
+        assert jax.config.jax_compilation_cache_dir == before
+        # grid cells compiling in under JAX's default 1 s are persisted
+        assert jax.config.jax_persistent_cache_min_compile_time_secs \
+            == CC.MIN_COMPILE_SECS < 1.0
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.5)
+        monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                           "2.5")
+        assert CC.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 2.5
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)

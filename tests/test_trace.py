@@ -163,6 +163,19 @@ def test_jax_profile_none_is_noop():
         pass
 
 
+def test_jax_profile_that_cannot_start_raises(monkeypatch, tmp_path):
+    """A requested device profile never degrades to an untraced run."""
+    import jax
+
+    def refuse(_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with SV.jax_profile(str(tmp_path)):
+            pass
+
+
 # --------------------------------------------------------------------------
 # validate_trace
 # --------------------------------------------------------------------------
