@@ -826,9 +826,7 @@ def apply_compiled(cp: CompiledPlan, coef: jnp.ndarray,
     if profile is not None:
         return _apply_profiled(cp, coef, cfg, executor, profile,
                                packed=False)
-    path = (cp.meta or {}).get("path", "reference")
-    h = _apply_stem(cp.stem, coef, cp.phi, path, cfg, executor)
-    return _run_blocks(cp, h, cfg, executor)
+    return _fold(compiled_steps(cp, cfg, executor=executor), coef)
 
 
 def apply_compiled_packed(cp: CompiledPlan, packed: jnp.ndarray,
@@ -852,27 +850,8 @@ def apply_compiled_packed(cp: CompiledPlan, packed: jnp.ndarray,
     if profile is not None:
         return _apply_profiled(cp, packed, cfg, executor, profile,
                                packed=True)
-    path = (cp.meta or {}).get("path", "reference")
-    st = cp.stem
-    n, bh, bw, k = packed.shape
-    if k != st.cin * st.w_in:
-        raise ValueError(
-            f"packed input has per-channel width {k / st.cin:g}, "
-            f"stem expects w_in={st.w_in} (cin={st.cin})")
-    if _stem_runs_gemm(st, path, cfg, executor):
-        from repro.kernels import tiling
-
-        h = tiling.packed_conv_apply(packed, st.conv)
-        h = tiling.packed_asm_apply(h, st.asm)
-    else:
-        # the spatial / per-layer stem executors consume the 64-wide
-        # layout; unpacking is an elementwise zero-pad (exact — lanes
-        # beyond w_in ≥ stem.bands are dropped by the stem conv anyway)
-        from repro.core.conv import pad_bands
-
-        coef = pad_bands(packed.reshape(n, bh, bw, st.cin, st.w_in))
-        h = _apply_stem(st, coef, cp.phi, path, cfg, executor)
-    return _run_blocks(cp, h, cfg, executor)
+    return _fold(compiled_steps(cp, cfg, executor=executor, packed=True),
+                 packed)
 
 
 def capture_compiled(cp: CompiledPlan, shape, *, packed: bool = False,
@@ -1005,30 +984,24 @@ def _make_head_fn(cp: CompiledPlan, w: int):
     return fn
 
 
-def _block_steps(cp: CompiledPlan, cfg: dispatchlib.DispatchConfig,
-                 executor: str | None):
-    """The post-stem schedule as an explicit ``(name, fn)`` list: one fn
-    per residual block plus the DC-read head.  :func:`_run_blocks` folds
-    exactly this list, so a per-step walk (profiling, attribution) runs
-    the same traced operations as the whole-schedule execution."""
-    steps = []
-    cur_w = cp.stem.w_out
-    for blk in cp.blocks:
-        steps.append((blk.name, _make_block_fn(blk, cur_w, cp.phi, cfg,
-                                               executor)))
-        cur_w = blk.w_out
-    steps.append(("head", _make_head_fn(cp, cur_w)))
-    return steps
+def _named(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)``: every HLO
+    instruction the step issues carries ``name`` in its ``op_name``
+    metadata (``jit(inner)/s2b0/...``), which is how a device profile's
+    ops map back to schedule steps.  Metadata only: the compiled program
+    is otherwise the same."""
+
+    def step(h):
+        with jax.named_scope(name):
+            return fn(h)
+
+    return step
 
 
-def _run_blocks(cp: CompiledPlan, h: jnp.ndarray,
-                cfg: dispatchlib.DispatchConfig,
-                executor: str | None = None) -> jnp.ndarray:
-    """Shared post-stem walk: fused/fallback steps, DC-read head."""
-    h = shard(h, "batch", None, None, None)
-    for _name, fn in _block_steps(cp, cfg, executor):
-        h = fn(h)
-    return h
+def _fold(steps, x: jnp.ndarray) -> jnp.ndarray:
+    for _name, fn in steps:
+        x = fn(x)
+    return x
 
 
 def compiled_steps(cp: CompiledPlan,
@@ -1043,7 +1016,9 @@ def compiled_steps(cp: CompiledPlan,
     :func:`apply_compiled_packed` — the steps are the *same closures*
     the whole-schedule walk executes, so per-step introspection (HLO
     attribution, profiled timing) observes the production schedule, not
-    a re-implementation of it.
+    a re-implementation of it.  Each step runs under
+    ``jax.named_scope(<name>)`` (:func:`_named`), so the served program
+    and the per-step walk carry the same step names in their metadata.
     """
     cfg = cp.cfg if cfg is None else cfg
     path = (cp.meta or {}).get("path", "reference")
@@ -1062,6 +1037,10 @@ def compiled_steps(cp: CompiledPlan,
                 h = tiling.packed_conv_apply(x, st.conv)
                 h = tiling.packed_asm_apply(h, st.asm)
             else:
+                # the spatial / per-layer stem executors consume the
+                # 64-wide layout; unpacking is an elementwise zero-pad
+                # (exact — lanes beyond w_in ≥ stem.bands are dropped by
+                # the stem conv anyway)
                 from repro.core.conv import pad_bands
 
                 coef = pad_bands(x.reshape(n, bh, bw, st.cin, st.w_in))
@@ -1070,7 +1049,14 @@ def compiled_steps(cp: CompiledPlan,
             h = _apply_stem(st, x, cp.phi, path, cfg, executor)
         return shard(h, "batch", None, None, None)
 
-    return [("stem", stem_fn)] + _block_steps(cp, cfg, executor)
+    steps = [("stem", stem_fn)]
+    cur_w = st.w_out
+    for blk in cp.blocks:
+        steps.append((blk.name, _make_block_fn(blk, cur_w, cp.phi, cfg,
+                                               executor)))
+        cur_w = blk.w_out
+    steps.append(("head", _make_head_fn(cp, cur_w)))
+    return [(name, _named(name, fn)) for name, fn in steps]
 
 
 class StepProfile:

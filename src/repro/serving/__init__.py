@@ -25,8 +25,9 @@ serving subsystem on top of the convert-once engine (``core.plan``):
   Prometheus-style text exposition with periodic snapshot writes;
 * :mod:`repro.serving.trace` — the flight recorder: a bounded ring of
   per-request spans (admission → queue → ingest-decode → batch-form →
-  pad/stage → device-dispatch → complete/fail/shed) exported as
-  Perfetto-loadable Chrome trace-event JSON;
+  device-dispatch [stack, pad/stage, launch, read] → complete →
+  complete/fail/shed) exported as Perfetto-loadable Chrome trace-event
+  JSON; the per-batch spans also reach a ``jax.profiler`` session;
 * :mod:`repro.serving.breaker` — a circuit breaker over service-level
   failures: fast-rejects (``ServiceUnavailable``) while the backend is
   evidently unhealthy, half-opens on a timer;

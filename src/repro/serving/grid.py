@@ -184,20 +184,22 @@ class GridCell:
                 f"cell {self.name} serves shape {self._shape}, "
                 f"got {tuple(rows.shape)}")
         tr = self._tracer
-        ta = tr.now() if tr.enabled else 0.0
-        host = self._pool.get(self._shape)
-        host[:n] = rows
-        if n < self.bucket:
-            host[n:] = 0.0
-        dev = jnp.array(host)
-        if tr.enabled:
-            # nested under the scheduler's device-dispatch span: the
-            # host-staging + host->device copy share of the dispatch
-            tr.span("device", "pad/stage", ta, tr.now(),
-                    args={"cell": self.name, "n": n,
-                          "pad": self.bucket - n, "rids": rids})
+        # nested under the scheduler's device-dispatch span: the
+        # host-staging + host->device copy share of the dispatch, then
+        # the executable's launch (asynchronous: it returns before the
+        # device is done)
+        with tr.scope("device", "pad/stage",
+                      args={"cell": self.name, "n": n,
+                            "pad": self.bucket - n, "rids": rids}
+                      if tr.enabled else None):
+            host = self._pool.get(self._shape)
+            host[:n] = rows
+            if n < self.bucket:
+                host[n:] = 0.0
+            dev = jnp.array(host)
         self.hits += 1
-        return self._fn(dev)
+        with tr.scope("device", "launch"):
+            return self._fn(dev)
 
     def warmup(self) -> None:
         host = self._pool.get(self._shape)

@@ -513,14 +513,16 @@ class BandElasticScheduler:
                                 args={"rids": [_rids[j] for j in indices]})
 
                 t0 = time.monotonic()
-                t0s = tr.now() if tr.enabled else 0.0
+                dargs = ({"n": len(reqs), "rids": [r.rid for r in reqs]}
+                         if tr.enabled else None)
                 try:
-                    if self.faults is not None:
-                        self.faults.on_ingest(reqs)
-                    coef, stats, errors = ingestlib.ingest_batch(
-                        [r.payload for r in reqs], quality=self.quality,
-                        grid=self.grid, channels=self.channels,
-                        on_error="isolate", on_shard=on_shard)
+                    with tr.scope("ingest", "ingest-decode", args=dargs):
+                        if self.faults is not None:
+                            self.faults.on_ingest(reqs)
+                        coef, stats, errors = ingestlib.ingest_batch(
+                            [r.payload for r in reqs], quality=self.quality,
+                            grid=self.grid, channels=self.channels,
+                            on_error="isolate", on_shard=on_shard)
                 except Exception as e:
                     # decode infrastructure died under the whole batch —
                     # fail these requests, keep the thread serving
@@ -543,10 +545,6 @@ class BandElasticScheduler:
                         self._idle.notify_all()
                     continue
                 wall = time.monotonic() - t0
-                if tr.enabled:
-                    tr.span("ingest", "ingest-decode", t0s, tr.now(),
-                            args={"n": len(reqs),
-                                  "rids": [r.rid for r in reqs]})
                 self._note_pool_restarts(ingestlib)
                 self.metrics.record_ingest(stats)
                 if errors:
@@ -681,6 +679,7 @@ class BandElasticScheduler:
         return min(slacks) if slacks else None
 
     def _run(self) -> None:
+        tr = self.tracer
         try:
             while True:
                 with self._lock:
@@ -694,6 +693,9 @@ class BandElasticScheduler:
                                            and not self._ingesting)):
                         break
                     now = time.monotonic()
+                    # batch-form: from the take to the dispatch loop
+                    form = tr.scope("scheduler", "batch-form")
+                    form.__enter__()
                     slack = self._head_slack_locked(now)
                     depth = self._pending_locked()
                     reqs, decoded, shed = self._take_batch_locked(now)
@@ -710,11 +712,13 @@ class BandElasticScheduler:
                     self._in_flight = len(reqs)
                 self._shed(shed)
                 if not reqs:
+                    if tr.enabled:
+                        form.args = {"n": 0, "shed": len(shed)}
+                    form.__exit__(None, None, None)
                     with self._idle:
                         self._in_flight = 0
                         self._idle.notify_all()
                     continue
-                tr = self.tracer
                 t_take = tr.now() if tr.enabled else 0.0
                 if tr.enabled:
                     # queue span closes here for the whole batch — once,
@@ -722,8 +726,13 @@ class BandElasticScheduler:
                     for r in reqs:
                         tr.span("request", "queue", r.t_enq, t_take,
                                 tid=r.rid)
+                    bucket = self.grid_engine.bucket_for(len(reqs))
+                    form.args = {"tier": self.tier_names[tier_ix],
+                                 "n": len(reqs), "bucket": bucket,
+                                 "kind": reqs[0].kind}
                 seq = self._dispatch_seq
                 self._dispatch_seq += 1
+                form.__exit__(None, None, None)
                 err: Exception | None = None
                 for _attempt in range(self.executor_retries + 1):
                     try:
@@ -786,52 +795,58 @@ class BandElasticScheduler:
             # pinned bucket-shaped buffer and zero-fills the pad tail.
             coef, ingest_wall = decoded
             kind = "bytes"
-            logits = np.asarray(ex.packed_fn(
-                ingestlib.pack_tiles(coef, ex.w_in), **kw))
+            with tr.scope("device", "stack"):
+                rows = ingestlib.pack_tiles(coef, ex.w_in)
+            out = ex.packed_fn(rows, **kw)
         else:
             kind = "coefficients"
-            logits = np.asarray(ex.coef_fn(np.stack(
-                [np.asarray(r.payload, np.float32) for r in reqs]), **kw))
+            with tr.scope("device", "stack"):
+                rows = np.stack([np.asarray(r.payload, np.float32)
+                                 for r in reqs])
+            out = ex.coef_fn(rows, **kw)
+        with tr.scope("device", "read"):
+            logits = np.asarray(out)
         wall = time.monotonic() - t0
-        if tr.enabled:
-            t1s = tr.now()
-            # batch-form covers take -> dispatch start (tier selection +
-            # tile packing); device-dispatch is exactly the interval the
-            # report's device_wall_s accumulates, so span sums reconcile
-            tr.span("scheduler", "batch-form", t_take, t0s,
-                    args={"tier": name, "n": n, "bucket": bucket,
-                          "kind": kind})
-            dargs = {"tier": name, "n": n, "bucket": bucket,
-                     "kind": kind, "rids": rids}
-            # --profile-grid cost annotations: the span carries the
-            # cell's static FLOPs and roofline-predicted wall, so a
-            # Perfetto query can put predicted-vs-measured on one track
-            cost = self.grid_engine.cost_for(f"{name}/{kind}/b{bucket}")
-            if cost:
-                dargs.update({k: cost[k] for k in ("flops", "predicted_us")
-                              if k in cost})
-            tr.span("device", "device-dispatch", t0s, t1s, args=dargs)
-            for r in reqs:
-                # flow arrow: this request's queue row -> its batch slice
-                tr.flow(r.rid, ("request", r.rid, t_take),
-                        ("device", 0, t0s))
-        # only device wall reaches the QoS EMA: host decode cost is
-        # band-independent, so folding it in would poison tier selection
-        self.selector.observe(tier_ix, wall, bucket=bucket)
-        self.metrics.record_batch(name, n, wall, queue_depth=depth,
-                                  ingest_s=ingest_wall, slots=bucket,
-                                  cell=f"{name}/{kind}/b{bucket}")
-        now = time.monotonic()
-        t_now = tr.now() if tr.enabled else 0.0
-        for i, r in enumerate(reqs):
-            r._complete(logits[i], name)
+        t1s = tr.now() if tr.enabled else 0.0
+        with tr.scope("scheduler", "complete",
+                      args={"n": n} if tr.enabled else None):
             if tr.enabled:
-                tr.instant("request", "complete", t=t_now, tid=r.rid,
-                           args={"tier": name})
-            self.metrics.record_request(
-                r.latency_s, tier=name,
-                deadline_missed=(r.deadline is not None
-                                 and now > r.deadline))
+                # device-dispatch is exactly the interval the report's
+                # device_wall_s accumulates, so span sums reconcile
+                dargs = {"tier": name, "n": n, "bucket": bucket,
+                         "kind": kind, "rids": rids}
+                # --profile-grid cost annotations: the span carries the
+                # cell's static FLOPs and roofline-predicted wall, so a
+                # Perfetto query can put predicted-vs-measured on one
+                # track
+                cost = self.grid_engine.cost_for(f"{name}/{kind}/b{bucket}")
+                if cost:
+                    dargs.update({k: cost[k] for k in ("flops",
+                                                       "predicted_us")
+                                  if k in cost})
+                tr.span("device", "device-dispatch", t0s, t1s, args=dargs)
+                for r in reqs:
+                    # flow arrow: this request's queue row -> its batch
+                    tr.flow(r.rid, ("request", r.rid, t_take),
+                            ("device", 0, t0s))
+            # only device wall reaches the QoS EMA: host decode cost is
+            # band-independent, so folding it in would poison tier
+            # selection
+            self.selector.observe(tier_ix, wall, bucket=bucket)
+            self.metrics.record_batch(name, n, wall, queue_depth=depth,
+                                      ingest_s=ingest_wall, slots=bucket,
+                                      cell=f"{name}/{kind}/b{bucket}")
+            now = time.monotonic()
+            t_now = tr.now() if tr.enabled else 0.0
+            for i, r in enumerate(reqs):
+                r._complete(logits[i], name)
+                if tr.enabled:
+                    tr.instant("request", "complete", t=t_now, tid=r.rid,
+                               args={"tier": name})
+                self.metrics.record_request(
+                    r.latency_s, tier=name,
+                    deadline_missed=(r.deadline is not None
+                                     and now > r.deadline))
         with self._idle:
             self._in_flight = 0
             self._batches += 1

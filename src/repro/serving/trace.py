@@ -8,20 +8,34 @@ in Perfetto (https://ui.perfetto.dev).
 
 Span taxonomy (one chain per request id, see TESTING.md):
 
-=============== ========== =====================================================
-track (pid)     name        interval
-=============== ========== =====================================================
-``scheduler``   admission*  ``submit()`` entry → accepted into a queue
-``scheduler``   batch-form  batch taken from the queue → executor dispatch
-                            (tier selection + tile packing)
-``ingest``      ingest-decode  one bytes batch through ``codec.ingest_batch``
-``ingest``      decode-shard   one spawn-pool shard of that batch (tid = shard)
+=============== ================ ==============================================
+track (pid)     name             interval
+=============== ================ ==============================================
+``scheduler``   batch-form       worker takes a batch (head slack, depth, the
+                                 take, tier selection, shedding) → dispatch
+``scheduler``   complete         after the logits read: QoS observe, metrics,
+                                 per-request completion (``args.n``)
+``ingest``      ingest-decode    one bytes batch through ``codec.ingest_batch``
+``ingest``      decode-shard     one spawn-pool shard of that batch (tid = shard)
 ``device``      device-dispatch  staged batch through the grid cell executable
-                            (the interval ``device_wall_s`` accumulates)
-``device``      pad/stage   host staging copy into the pinned bucket buffer
-``request``     admission / queue   per-request rows (tid = request id)
-``request``     complete / fail / shed   terminal instants closing the chain
-=============== ========== =====================================================
+                                 (the interval ``device_wall_s`` accumulates)
+``device``      stack            (nested) ``np.stack`` of the coefficient
+                                 payloads, or ``pack_tiles`` of a bytes batch
+``device``      pad/stage        (nested) staging copy into the pinned bucket
+                                 buffer and the host→device copy
+``device``      launch           (nested) the captured executable's call
+``device``      read             (nested) ``np.asarray`` of the logits: waits
+                                 for the device, copies to the host
+``request``     admission/queue  per-request rows (tid = request id)
+``request``     complete/fail/shed  terminal instants closing the chain
+=============== ================ ==============================================
+
+The per-batch spans (``batch-form``, ``complete``, the four children of
+``device-dispatch``) and ``ingest-decode`` are recorded through
+:meth:`Tracer.scope`, which also opens a ``jax.profiler.TraceAnnotation``
+named ``<track>/<name>`` on the calling thread: under a profiler session
+they land in the profile's host plane, on the device trace's clock.
+Per-request events stay in the ring only.
 
 Instant events mark tier switches, breaker transitions, ingest-pool
 restarts, and post-warmup compiles.  Batches link to their member
@@ -36,7 +50,8 @@ The clock is injectable (tests drive it deterministically); timestamps
 are exported relative to tracer construction in microseconds.
 
 :data:`NULL_TRACER` is the disabled no-op twin — the scheduler threads
-it unconditionally so tracing costs one attribute check when off.
+it unconditionally so tracing costs one attribute check when off (its
+``scope`` returns one shared ``nullcontext``: an empty ``with``).
 :func:`validate_trace` is the schema/chain checker CI and the tests
 share.  :func:`jax_profile` optionally brackets the same window with
 ``jax.profiler`` so a device-level profile can be captured alongside.
@@ -50,6 +65,8 @@ import math
 import threading
 import time
 from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -83,6 +100,9 @@ class NullTracer:
     def span(self, *a, **kw) -> None:
         pass
 
+    def scope(self, *a, **kw) -> contextlib.nullcontext:
+        return _NULL_SCOPE
+
     def instant(self, *a, **kw) -> None:
         pass
 
@@ -97,6 +117,35 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+_NULL_SCOPE = contextlib.nullcontext()
+
+
+class _Scope:
+    """One :meth:`Tracer.scope`: a profiler annotation while open, a ring
+    span on exit.  ``args`` is read at exit, so a caller may set it once
+    it knows them."""
+
+    __slots__ = ("_tracer", "track", "name", "tid", "args", "_ann", "_t0")
+
+    def __init__(self, tracer: "Tracer", track: str, name: str, tid: int,
+                 args: dict | None):
+        self._tracer = tracer
+        self.track = track
+        self.name = name
+        self.tid = tid
+        self.args = args
+
+    def __enter__(self) -> "_Scope":
+        self._ann = TraceAnnotation(f"{self.track}/{self.name}")
+        self._ann.__enter__()
+        self._t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = self._tracer.now()
+        self._ann.__exit__(*exc)
+        self._tracer.span(self.track, self.name, self._t0, t1, tid=self.tid,
+                          args=self.args)
 
 
 class Tracer:
@@ -147,6 +196,14 @@ class Tracer:
         """One completed interval ``[t0, t1]`` (absolute clock readings)."""
         self._push(("X", track, tid, name, t0 - self._t0,
                     max(t1 - t0, 0.0), args))
+
+    def scope(self, track: str, name: str, *, tid: int = 0,
+              args: dict | None = None) -> _Scope:
+        """Context manager recording the ``with`` block as a ring span,
+        exactly as :meth:`span` would, inside a
+        ``jax.profiler.TraceAnnotation("<track>/<name>")`` on the calling
+        thread, so that a profiler session sees it too."""
+        return _Scope(self, track, name, tid, args)
 
     def instant(self, track: str, name: str, *, t: float | None = None,
                 tid: int = 0, args: dict | None = None) -> None:

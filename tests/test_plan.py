@@ -12,8 +12,13 @@ Contracts:
 * band autotuning is monotone in the energy budget (tighter budget ⇒
   fewer bands, never more);
 * the precomputed path's residual join uses ``poollib.residual_add`` and
-  agrees with the per-layer path through the projection shortcut.
+  agrees with the per-layer path through the projection shortcut;
+* every step of a compiled schedule runs under ``jax.named_scope(<step>)``:
+  the served program's instructions name their step in ``op_name``, and
+  nothing but the metadata changes.
 """
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -266,3 +271,74 @@ def test_prepare_plan_rebuilds_plan_resolved_on_another_platform(tmp_path):
     assert PL.load_plan(str(tmp_path)).provenance["platform"] == "tpu"
     plan3, _, info3 = S.prepare_plan(ns, cfg, dcfg)
     assert info3["built"] and plan3.provenance["platform"] == here
+
+
+# --------------------------------------------------------------------------
+# Named schedule steps in the compiled program's metadata
+# --------------------------------------------------------------------------
+
+# instructions that issue device work, with their op_name metadata
+_ISSUING = re.compile(
+    r'^\s*(?:ROOT\s+)?%(\S+) = (?:\([^=]*?\)|\S+) '
+    r'(convolution|dot|custom-call|while|fusion)\(.*op_name="([^"]*)"')
+
+
+@pytest.fixture(scope="module")
+def small_compiled():
+    spec = R.ResNetSpec(widths=(6, 8), num_classes=10)
+    params, state = R.init_resnet(jax.random.PRNGKey(0), spec)
+    plan = PL.build_plan(params, state, spec,
+                         dispatch=DSP.DispatchConfig(path="reference"))
+    return PL.compile_plan(plan)
+
+
+def _served_text(cp, packed, executor):
+    """Compiled text of the grid cells' captured entry, 2 images of 16 px."""
+    shape = ((2, 2, 2, 3 * cp.stem.w_in) if packed else (2, 2, 2, 3, 64))
+    return PL.capture_compiled(cp, shape, packed=packed, executor=executor,
+                               donate=False).lower().compile().as_text()
+
+
+def _strip_metadata(text):
+    """The instructions alone: no metadata, no stack-frame tables."""
+    lines = [line for line in text.splitlines() if not re.match(
+        r"(FileNames|FunctionNames|FileLocations|StackFrames)$|\d+ ", line)]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+@pytest.mark.parametrize("executor", [None, "gemm"])
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["coefficients", "packed"])
+def test_named_scopes_tag_every_instruction_with_one_step(
+        small_compiled, packed, executor):
+    """Every convolution, dot, custom call, loop and fusion of the served
+    program that has an ``op_name`` names exactly one schedule step, and
+    every step of ``compiled_steps`` appears."""
+    cp = small_compiled
+    names = [name for name, _ in PL.compiled_steps(cp)]
+    assert names == ["stem"] + [b.name for b in cp.blocks] + ["head"]
+    text = _served_text(cp, packed, executor)
+    issuing = [m for m in map(_ISSUING.match, text.splitlines()) if m]
+    assert issuing
+    for m in issuing:
+        steps = [c for c in m.group(3).split("/") if c in names]
+        assert len(steps) == 1, (m.group(1), m.group(3))
+    seen = {c for op in re.findall(r'op_name="([^"]*)"', text)
+            for c in op.split("/") if c in names}
+    assert seen == set(names)
+
+
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["coefficients", "packed"])
+def test_named_scopes_change_only_metadata(small_compiled, monkeypatch,
+                                           packed):
+    """With the step scopes taken out, the compiled program differs only
+    in its metadata."""
+    scoped = _served_text(small_compiled, packed, "gemm")
+    monkeypatch.setattr(PL, "_named", lambda name, fn: fn)
+    plain = _served_text(small_compiled, packed, "gemm")
+    assert "/s0b0/" in scoped and "/s0b0/" not in plain
+    assert scoped != plain
+    stripped = _strip_metadata(scoped)
+    assert stripped.count(" fusion(") > 1 and "op_name" not in stripped
+    assert stripped == _strip_metadata(plain)

@@ -156,6 +156,66 @@ def test_null_tracer_is_inert():
     assert NULL_TRACER.now() == 0.0
 
 
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that logs enters and
+    exits."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_scope_records_ring_span_inside_an_annotation(monkeypatch):
+    """``Tracer.scope`` records the ``with`` block exactly as ``span``
+    would, with args read at exit, inside an annotation named
+    ``<track>/<name>``; an exception still closes both."""
+    from repro.serving import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", _Annotations)
+    _Annotations.log = []
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    with tr.scope("device", "stack", tid=3) as sc:
+        clk.tick(0.5)
+        sc.args = {"n": 4}
+    with pytest.raises(RuntimeError):
+        with tr.scope("scheduler", "complete"):
+            clk.tick(0.25)
+            raise RuntimeError("boom")
+    assert _Annotations.log == [("enter", "device/stack"),
+                                ("exit", "device/stack"),
+                                ("enter", "scheduler/complete"),
+                                ("exit", "scheduler/complete")]
+    (a, b) = tr.events()
+    assert a == ("X", "device", 3, "stack", 0.0, 0.5, {"n": 4})
+    assert b == ("X", "scheduler", 0, "complete", 0.5, 0.25, None)
+
+
+def test_null_scope_is_one_shared_empty_context(monkeypatch):
+    """Disabled tracing: ``scope`` hands back one preallocated
+    ``nullcontext`` and opens no annotation."""
+    import contextlib
+
+    from repro.serving import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", _Annotations)
+    _Annotations.log = []
+    sc = NULL_TRACER.scope("device", "stack", args={"n": 1})
+    assert sc is NULL_TRACER.scope("scheduler", "complete")
+    assert isinstance(sc, contextlib.nullcontext)
+    with sc:
+        pass
+    assert _Annotations.log == []
+    assert NULL_TRACER.events() == []
+
+
 def test_jax_profile_none_is_noop():
     with SV.jax_profile(None):
         pass
@@ -272,6 +332,12 @@ def setup():
     return spec, coef, plan, ladder
 
 
+#: the spans every dispatched batch leaves, as "<track>/<name>"
+PER_BATCH = ("scheduler/batch-form", "device/stack", "device/pad/stage",
+             "device/launch", "device/read", "scheduler/complete")
+DEVICE_CHILDREN = PER_BATCH[1:5]
+
+
 def _sched(ladder, coef, tracer, **kw):
     kw.setdefault("batch", 2)
     kw.setdefault("grid", tuple(coef.shape[1:3]))
@@ -316,11 +382,12 @@ def test_traced_run_closes_chains_and_reconciles_walls(setup, tmp_path):
     assert summ["requests"] == n_coef + n_bytes
     assert summ["open_chains"] == []
     assert summ["failed"] == summ["shed"] == 0
-    # each batch leaves one batch-form + one device-dispatch + one
-    # pad/stage span; bytes batches add ingest-decode spans
+    # each batch leaves one batch-form, one device-dispatch with its four
+    # children (stack, pad/stage, launch, read) and one complete span;
+    # bytes batches add ingest-decode spans
     by = summ["spans_by_name"]
-    assert by["scheduler/batch-form"] == by["device/device-dispatch"]
-    assert by["device/pad/stage"] == by["device/device-dispatch"]
+    for name in PER_BATCH:
+        assert by[name] == by["device/device-dispatch"], name
     assert by["ingest/ingest-decode"] >= 1
     assert summ["flows"] == 2 * (n_coef + n_bytes)
 
@@ -386,3 +453,64 @@ def test_untraced_scheduler_records_nothing(setup):
         r = s.submit(np.asarray(coef[0]))
         assert np.isfinite(r.result(timeout=60)).all()
     assert s.tracer.events() == []
+
+
+def _host_events(log_dir):
+    """``name -> [(start_ns, end_ns)]`` of the profile's host-plane
+    events."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_per_batch_scopes_reach_the_profiler(setup, tmp_path):
+    """Under a profiler session every dispatched batch leaves each
+    per-batch span once in the ring and once as a host-plane event; the
+    four device children nest in their device-dispatch span; the trace
+    still validates; the disabled tracer's scope leaves no event."""
+    spec, coef, plan, ladder = setup
+    tracer = SV.Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with _sched(ladder, coef, tracer) as s:
+        s.warmup()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            reqs = [s.submit(np.asarray(coef[i % coef.shape[0]]))
+                    for i in range(6)]
+            reqs += [s.submit(d, kind="bytes") for d in _jpeg_traffic(4)]
+            assert all(np.isfinite(r.result(timeout=120)).all()
+                       for r in reqs)
+            s.drain()  # every batch's complete span has closed
+            with NULL_TRACER.scope("device", "null-probe"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    host = _host_events(tmp_path)
+    spans = [e for e in tracer.events() if e[0] == "X"]
+    dispatches = [(t, t + d) for _, track, _, name, t, d, _ in spans
+                  if (track, name) == ("device", "device-dispatch")]
+    assert len(dispatches) >= 5  # batch 2: 6 coefficient + 4 bytes images
+    for full in PER_BATCH:
+        ring = [e for e in spans if f"{e[1]}/{e[3]}" == full]
+        assert len(ring) == len(dispatches), full
+        assert len(host.get(full, [])) == len(dispatches), full
+    for _, track, _, name, t, d, _ in spans:
+        if f"{track}/{name}" in DEVICE_CHILDREN:
+            assert sum(a <= t and t + d <= b for a, b in dispatches) == 1
+    assert "device/null-probe" not in host
+    ingest = [e for e in spans if (e[1], e[3]) == ("ingest", "ingest-decode")]
+    assert len(host["ingest/ingest-decode"]) == len(ingest) >= 1
+    assert validate_trace(tracer.export())["open_chains"] == []
