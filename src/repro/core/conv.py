@@ -296,12 +296,17 @@ def _jpeg_conv_factored(coef, kernel, stride, *, quality, in_scaled,
 
     coef: (N, bh, bw, Cin, 64) -> (N, bh/s, bw/s, Cout, 64).
 
+    J̃ and J are each one dense 64×64 matmul per block (zigzag order, 2-D
+    DCT and quantization folded into one constant matrix, see
+    :mod:`repro.core.jpeg`); C is a spatial convolution.
+
     ``bands`` truncates the input and output coefficient sets so the result
-    matches the band-truncated materialised operator (here the truncation
-    is a zeroing — this path's win is memory, not the §6 sparsity FLOPs).
+    matches the band-truncated materialised operator: the decode meets only
+    the first ``bands`` rows of its matrix, and the encoded output is zeroed
+    past ``bands`` (this path's win is memory, not the §6 sparsity FLOPs).
     """
     if bands < coef.shape[-1]:
-        coef = pad_bands(coef[..., :bands])
+        coef = coef[..., :bands]
     img = jpeglib.jpeg_decode(jnp.moveaxis(coef, 3, 1), scaled=in_scaled,
                               quality=quality)
     out = spatial_conv(img, kernel, stride)

@@ -31,6 +31,12 @@ canonical-qtable-normalized  a *file's* quantized integers rescaled by
                              files with arbitrary quantization tables
 ===========================  ==============================================
 
+Each block's transform is one constant linear map: ``jpeg_decode`` is
+zigzag coefficients ``@ diag(q) · R`` and ``jpeg_encode`` flat pixels
+``@ Rᵀ · diag(1/q)``, with ``R = dct.reconstruction_matrix()`` (64×64:
+the separable 2-D DCT and the zigzag order in one matrix).  So both lower
+to one dense matmul, never to a gather over the coefficient axis.
+
 Note the orthonormal 8×8 DCT here coincides with the JPEG standard's DCT
 definition, and steps 5+ (rounding, entropy coding) live in
 ``repro.codec`` (``bitstream``/``encode``) — this module stays the
@@ -74,6 +80,15 @@ def unblock_image(blocks: jnp.ndarray) -> jnp.ndarray:
     return blocks.reshape(*lead, bh * b1, bw * b2)
 
 
+def _quant_steps(quality: int, scaled: bool,
+                 qtable: np.ndarray | None) -> np.ndarray:
+    """Zigzag-ordered quantization steps ``q`` (all ones when not ``scaled``)."""
+    if not scaled:
+        return np.ones(dctlib.NFREQ)
+    q = qtable if qtable is not None else dctlib.quantization_table(quality)
+    return np.asarray(q, np.float64).reshape(dctlib.NFREQ)
+
+
 def jpeg_encode(
     img: jnp.ndarray,
     *,
@@ -81,16 +96,17 @@ def jpeg_encode(
     scaled: bool = True,
     qtable: np.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Steps 1–4 of JPEG encoding: ``(..., H, W) -> (..., H/8, W/8, 64)``."""
-    d = jnp.asarray(dctlib.dct_matrix(), img.dtype)
-    zz = dctlib.zigzag_permutation()
+    """Steps 1–4 of JPEG encoding: ``(..., H, W) -> (..., H/8, W/8, 64)``.
+
+    One dense matmul per block: flat pixels ``@ Rᵀ · diag(1/q)``, where
+    ``R`` is :func:`repro.core.dct.reconstruction_matrix`.  The 2-D DCT,
+    the zigzag order and the division by ``q`` (when ``scaled``) are one
+    constant 64×64 matrix.
+    """
+    q = _quant_steps(quality, scaled, qtable)
+    fwd = jnp.asarray(dctlib.reconstruction_matrix().T / q, img.dtype)
     blocks = block_image(img)
-    coef = jnp.einsum("am,...mn,bn->...ab", d, blocks, d)
-    coef = coef.reshape(*coef.shape[:-2], dctlib.NFREQ)[..., zz]
-    if scaled:
-        q = qtable if qtable is not None else dctlib.quantization_table(quality)
-        coef = coef / jnp.asarray(q, coef.dtype)
-    return coef
+    return blocks.reshape(*blocks.shape[:-2], dctlib.NFREQ) @ fwd
 
 
 def jpeg_decode(
@@ -100,16 +116,21 @@ def jpeg_decode(
     scaled: bool = True,
     qtable: np.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Inverse of :func:`jpeg_encode` (no rounding — exact inverse)."""
-    if scaled:
-        q = qtable if qtable is not None else dctlib.quantization_table(quality)
-        coef = coef * jnp.asarray(q, coef.dtype)
-    inv_zz = np.argsort(dctlib.zigzag_permutation())
-    coef = coef[..., inv_zz]
-    coef = coef.reshape(*coef.shape[:-1], dctlib.BLOCK, dctlib.BLOCK)
-    d = jnp.asarray(dctlib.dct_matrix(), coef.dtype)
-    blocks = jnp.einsum("am,...ab,bn->...mn", d, coef, d)
-    return unblock_image(blocks)
+    """Inverse of :func:`jpeg_encode` (no rounding — exact inverse).
+
+    One dense matmul per block: zigzag coefficients ``@ diag(q) · R`` give
+    the block's flat pixels.  A trailing axis shorter than 64 holds the
+    first zigzag coefficients (the rest are zero) and meets only the
+    matching rows of the matrix.
+    """
+    nf = coef.shape[-1]
+    if nf > dctlib.NFREQ:
+        raise ValueError(f"{nf} coefficients per block, at most {dctlib.NFREQ}")
+    q = _quant_steps(quality, scaled, qtable)
+    rec = q[:, None] * dctlib.reconstruction_matrix()
+    flat = jnp.matmul(coef, jnp.asarray(rec[:nf], coef.dtype))
+    return unblock_image(flat.reshape(*flat.shape[:-1], dctlib.BLOCK,
+                                      dctlib.BLOCK))
 
 
 def jpeg_round_trip_lossy(img: jnp.ndarray, *, quality: int = 50) -> jnp.ndarray:
@@ -129,10 +150,8 @@ def jpeg_tensor(
 ) -> np.ndarray:
     """The paper's ``J`` (Eq. 8) as ``(h, w, h/8, w/8, 64)``: pixels->coeffs."""
     b = dctlib.BLOCK
-    r = dctlib.reconstruction_matrix()  # (64 zigzag coef, 64 flat pixel)
-    fwd = r.T.copy()  # (pixel, coef): forward DCT in zigzag order
-    if scaled:
-        fwd = fwd / dctlib.quantization_table(quality)[None, :]
+    # (pixel, coef): forward DCT in zigzag order, ÷ q when scaled
+    fwd = dctlib.reconstruction_matrix().T / _quant_steps(quality, scaled, None)
     j = np.zeros((h, w, h // b, w // b, b * b))
     for x in range(h // b):
         for y in range(w // b):
@@ -147,9 +166,9 @@ def ijpeg_tensor(
 ) -> np.ndarray:
     """The paper's ``J̃`` (Eq. 10) as ``(h/8, w/8, 64, h, w)``: coeffs->pixels."""
     b = dctlib.BLOCK
-    rec = dctlib.reconstruction_matrix()  # (coef, pixel)
-    if scaled:
-        rec = rec * dctlib.quantization_table(quality)[:, None]
+    # (coef, pixel), × q when scaled
+    rec = (_quant_steps(quality, scaled, None)[:, None]
+           * dctlib.reconstruction_matrix())
     jt = np.zeros((h // b, w // b, b * b, h, w))
     for x in range(h // b):
         for y in range(w // b):

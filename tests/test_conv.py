@@ -96,3 +96,19 @@ def test_block_offsets():
     assert C.block_offsets(2, 1) == (0, 1)
     with pytest.raises(ValueError):
         C.block_offsets(1, 4)
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("bands", [8, 24, 48])
+@pytest.mark.parametrize("r", [1, 3])
+def test_factored_matches_exploded_at_bands(rng, stride, bands, r):
+    """The factored path (two dense 64×64 transforms around a spatial conv)
+    equals the band-truncated materialised operator, both conventions."""
+    k = jnp.asarray(rng.normal(size=(4, 3, r, r)) * 0.3, jnp.float32)
+    coef = jnp.asarray(rng.normal(size=(2, 4, 4, 3, 64)), jnp.float32)
+    kw = dict(quality=50, in_scaled=True, out_scaled=False)
+    xi = C.explode(k, stride, bands=bands, **kw)
+    want = C.pad_bands(C.apply_exploded(coef, xi, stride))
+    got = C._jpeg_conv_factored(coef, k, stride, bands=bands, **kw)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())

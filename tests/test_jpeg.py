@@ -1,5 +1,6 @@
 """The JPEG linear map: roundtrips, explicit J/J~ tensors, linearity."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
@@ -63,3 +64,73 @@ def test_lossy_roundtrip_reduces_energy(rng):
 def test_block_unblock_inverse(rng):
     img = rng.normal(size=(3, 24, 16))
     assert np.allclose(J.unblock_image(J.block_image(jnp.asarray(img))), img)
+
+
+_QT = np.linspace(1.0, 40.0, D.NFREQ)  # an explicit, non-standard q-table
+
+
+def _numpy_encode(img, q):
+    """Blocks -> separable 2-D DCT -> zigzag -> ÷ q, in float64 numpy."""
+    *lead, h, w = img.shape
+    blocks = img.reshape(*lead, h // 8, 8, w // 8, 8).swapaxes(-3, -2)
+    flat = D.dct2(blocks).reshape(*blocks.shape[:-2], D.NFREQ)
+    return flat[..., D.zigzag_permutation()] / q
+
+
+def _numpy_decode(coef, q):
+    """× q -> un-zigzag -> separable inverse DCT -> unblock, float64 numpy."""
+    flat = np.empty_like(coef)
+    flat[..., D.zigzag_permutation()] = coef * q
+    blocks = D.idct2(flat.reshape(*flat.shape[:-1], 8, 8))
+    *lead, bh, bw, _, _ = blocks.shape
+    return blocks.swapaxes(-3, -2).reshape(*lead, bh * 8, bw * 8)
+
+
+_CONVENTIONS = [
+    pytest.param(dict(scaled=True), D.quantization_table(50), id="scaled"),
+    pytest.param(dict(scaled=True, quality=90), D.quantization_table(90),
+                 id="scaled-q90"),
+    pytest.param(dict(scaled=False), np.ones(D.NFREQ), id="unscaled"),
+    pytest.param(dict(scaled=True, qtable=_QT), _QT, id="qtable"),
+]
+
+
+def _rel_err(got, want):
+    return np.abs(np.asarray(got, np.float64) - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("kw,q", _CONVENTIONS)
+def test_encode_matches_numpy_definition(rng, kw, q):
+    img = rng.normal(size=(2, 3, 16, 24))
+    got = J.jpeg_encode(jnp.asarray(img, jnp.float32), **kw)
+    assert _rel_err(got, _numpy_encode(img, q)) < 1e-5
+
+
+@pytest.mark.parametrize("kw,q", _CONVENTIONS)
+def test_decode_matches_numpy_definition(rng, kw, q):
+    coef = rng.normal(size=(2, 3, 2, 3, D.NFREQ))
+    got = J.jpeg_decode(jnp.asarray(coef, jnp.float32), **kw)
+    assert _rel_err(got, _numpy_decode(coef, q)) < 1e-5
+
+
+@pytest.mark.parametrize("nf", [1, 24, 48])
+def test_decode_of_leading_coefficients_zero_fills(rng, nf):
+    """Fewer than 64 coefficients decode as if the rest were zero."""
+    coef = rng.normal(size=(3, 2, 2, nf)).astype(np.float32)
+    full = np.pad(coef, [(0, 0)] * 3 + [(0, D.NFREQ - nf)])
+    np.testing.assert_allclose(J.jpeg_decode(jnp.asarray(coef)),
+                               J.jpeg_decode(jnp.asarray(full)), atol=1e-6)
+
+
+@pytest.mark.parametrize("fn,shape", [
+    (J.jpeg_encode, (2, 3, 16, 24)),
+    (J.jpeg_decode, (2, 3, 2, 3, D.NFREQ)),
+], ids=["encode", "decode"])
+@pytest.mark.parametrize("kw,q", _CONVENTIONS)
+def test_transform_lowers_without_gather(fn, shape, kw, q):
+    """The zigzag order is folded into a dense matrix: no gather is emitted
+    (on a TPU a gather over the coefficient axis lowers to a loop)."""
+    hlo = jax.jit(lambda x: fn(x, **kw)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.float32)).as_text()
+    assert "stablehlo.gather" not in hlo
+    assert "dot_general" in hlo

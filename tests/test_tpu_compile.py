@@ -6,6 +6,9 @@ attached) at the operator shapes the served plans run, and asserts that
 the program holds the Mosaic kernel (``tpu_custom_call``).  Mosaic refuses
 what the Pallas interpreter accepts — strided vector slices, lane-splitting
 reshapes, VMEM overcommit — so these guard the chip path at no chip time.
+The factored convolution (XLA, no Pallas) is compiled the same way at
+published widths and must hold no loop and no gather: a gather over the
+coefficient axis lowers to a ``while`` over its 64 lanes on a TPU.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
@@ -87,3 +90,28 @@ def test_asm_relu_compiles_for_v5e(one_chip, rows, nf):
     compiled = asm_relu_pallas.lower(_spec((rows, nf), one_chip), 14,
                                      interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _factored_shapes():
+    """(name, grid, cin, cout, r, stride) of published-width factored convs:
+    stage 0's 3x3, and s1b0's stride-2 3x3 and 1x1 projection."""
+    full = get_config("jpeg-resnet")
+    w, g = full.widths, full.image_size // 8
+    return [
+        ("full/s0b0", g, w[0], w[0], 3, 1),
+        ("full/s1b0/conv1", g, w[0], w[1], 3, 2),
+        ("full/s1b0/proj", g, w[0], w[1], 1, 2),
+    ]
+
+
+@pytest.mark.parametrize("name,grid,cin,cout,r,stride", _factored_shapes(),
+                         ids=[c[0] for c in _factored_shapes()])
+def test_factored_conv_compiles_without_loops(one_chip, name, grid, cin,
+                                              cout, r, stride):
+    """Batch 8: below it the TPU compiler lowers a gather differently."""
+    conv = jax.jit(lambda c, k: C._jpeg_conv_factored(
+        c, k, stride, quality=50, in_scaled=False, out_scaled=False))
+    text = conv.lower(_spec((8, grid, grid, cin, 64), one_chip),
+                      _spec((cout, cin, r, r), one_chip)).compile().as_text()
+    assert "while(" not in text
+    assert "gather(" not in text
