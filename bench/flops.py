@@ -1,35 +1,17 @@
 """Operation and byte counts, from shapes alone.
 
-``model_flops`` is the work of the spatial network that the JPEG-domain
-network equals: 2 x multiply-adds of every convolution and of the
-classifier, whatever implements them (materialised Ξ, factored, band
-truncated).  It is what ``step_mfu`` divides by the device time.
-
-``kernel_cost`` gives a Pallas kernel's operations and bytes from its
+``asm_relu_cost`` gives a Pallas kernel's operations and bytes from its
 operand shapes as they appear in a compiled program: the least work the
 kernel's algorithm needs, so that the least time they imply bounds the
-measured time from below.
+measured time from below.  A model's FLOPs are its architecture
+module's (``archs/<arch>.py``, ``model_flops``).
 """
 from __future__ import annotations
 
 import re
 
-from bench.system import stages
-
 _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "s8": 1, "u8": 1,
                 "f8e4m3fn": 1}
-
-
-def model_flops(cfg: dict) -> float:
-    """FLOPs of one image through the configuration's spatial network."""
-    size = cfg["image_size"]
-    macs = size * size * cfg["widths"][0] * cfg["in_channels"] * 9
-    for _name, s, cin, w in stages(cfg):
-        size //= s
-        macs += size * size * w * (cin * 9 + w * 9 + (cin if s != 1 or
-                                                      cin != w else 0))
-    macs += cfg["widths"][-1] * cfg["num_classes"]
-    return 2.0 * macs
 
 
 def parse_shape(text: str) -> tuple[str, tuple[int, ...]]:

@@ -47,7 +47,7 @@ class RunView:
     """What a per-layer metric reader sees (``metrics/<name>.py``)."""
 
     def __init__(self, *, window, spans, trace, trace_offset, kernels,
-                 modules, config, peak):
+                 modules, config, arch, peak):
         self.window = window
         self.spans = spans
         self.trace = trace
@@ -55,6 +55,7 @@ class RunView:
         self.kernels = kernels
         self.modules = modules
         self.config = config
+        self.arch = arch
         self.peak = peak
         self.notes: list[str] = []
 
@@ -146,12 +147,13 @@ def images_per_s(records, win) -> float:
     return len(inside) / (inside[-1] - start)
 
 
-def check(answers, inputs, params, state, cfg, traffic, seed, *,
+def check(answers, inputs, params, state, cfg, arch, traffic, seed, *,
           control: bool = False) -> dict:
     """Widest gap between served and reference logits over a sample,
     drawn from the seed, of ``answers`` (``(item, tier, logits)``), as a
-    share of the sample's logit scale.  With ``control`` the logits
-    compared are the control's, in the served ones' place."""
+    share of the sample's logit scale; the reference is the network of
+    ``arch``, the configuration's architecture module.  With ``control``
+    the logits compared are the control's, in the served ones' place."""
     from bench import data, reference
     from bench.system import tier_caps
 
@@ -170,7 +172,7 @@ def check(answers, inputs, params, state, cfg, traffic, seed, *,
     served, ref = [], []
     for (q, bands), rs in sorted(groups.items()):
         items = [item for item, _ in rs]
-        args = (params, state, cfg,
+        args = (params, state, cfg, arch,
                 np.stack([inputs["luma"][i] for i in items]),
                 np.stack([inputs["chroma"][i] for i in items]),
                 np.rint(data.ijg_table(q)))
@@ -204,6 +206,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     ``BENCHMARK.json`` (``spec.cell``)."""
     cell = cell or speclib.cell(root, workload)
     cfg, traffic, w = cell["config"], cell["traffic"], cell["workload"]
+    arch = cell["arch"]
     import jax
 
     devs = jax.devices()
@@ -223,7 +226,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     timings: dict = {}
     inputs = make_inputs(seed, cfg, traffic, timings)
     t = time.monotonic()
-    params, state = system.weights(seed, cfg)
+    params, state = system.weights(seed, cfg, arch)
     timings["weights_s"] = time.monotonic() - t
     tracer = None
     if trace:
@@ -237,7 +240,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
 
         tracer = serving.Tracer(capacity=1 << 22, clock=clock)
         tracer.origin = origin[0]
-    sched = system.build(cfg, traffic, params, state, tracer=tracer,
+    sched = system.build(cfg, traffic, arch, params, state, tracer=tracer,
                          timings=timings)
     kind = traffic["kind"]
     payloads = inputs["payloads"]
@@ -323,7 +326,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
                        trace=reduced,
                        trace_offset=reduced["window"][0] - win.opened,
                        kernels=kernels, modules=modules, config=cfg,
-                       peak=peak)
+                       arch=arch, peak=peak)
         for m in cell["per_layer"]:
             value = speclib.reader(cell["metrics_dir"], m["name"])(view)
             if value is not None:
@@ -338,7 +341,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
             f"{ {k: len(v) for k, v in reduced['modules'].items()} }")
 
     t = time.monotonic()
-    result = check(answers, inputs, params, state, cfg, traffic, seed,
+    result = check(answers, inputs, params, state, cfg, arch, traffic, seed,
                    control=control)
     limits = {"logit_gap": cfg["limits"]["logit_gap"],
               "compiles_in_window": 0, "failed": 0}

@@ -6,8 +6,8 @@ Executions are the trace's ``XLA Modules`` events of the served cells'
 module that lie wholly in the window; each is matched to the program's
 ``device/device-dispatch`` span around it (host clock mapped onto the
 trace's) for its count of real images.  FLOPs are those of the spatial
-network the JPEG-domain one equals (``flops.model_flops``)."""
-from bench import flops
+network the JPEG-domain one equals (the architecture module's
+``model_flops``)."""
 
 
 def read(run):
@@ -29,5 +29,5 @@ def read(run):
             seconds += dur
     if not seconds:
         return None
-    achieved = flops.model_flops(run.config) * images / seconds
+    achieved = run.arch.model_flops(run.config) * images / seconds
     return 100.0 * achieved / run.peak["bf16_flops_per_s"]

@@ -9,18 +9,12 @@ What the transform domain adds is band truncation: an operator at ``b``
 bands keeps only the first ``b`` zigzag coefficients of each 8x8 block,
 on its input and on its output, and so does ReLU.  Here that is a
 64x64 projection ``P_b`` on each block's pixels.  So the reference is a
-spatial network with ``P_b`` wherever the program truncates:
-
-    stem:   h = P(relu(P(bn(conv(P(x))))))
-    block:  s = h, or P(conv_1x1(h)) with a projection
-            a = P(relu(P(bn1(conv1(h)))))
-            h = P(relu(P(bn2(conv2(a))) + s))
-    head:   mean pixel of each channel @ W + b
-
-in float32 at ``"highest"`` matmul precision, with centered zero
-padding.  ``P`` is the identity at 64 bands.  It imports nothing of the
-program and uses only the weights and the JPEG integers that the
-benchmark itself made.
+spatial network with ``P_b`` wherever the program truncates; the
+configuration's architecture module (``archs/<arch>.py``, ``forward``)
+writes out the layers, and this module runs it in float32 at
+``"highest"`` matmul precision.  ``P`` is the identity at 64 bands.  It
+imports nothing of the program and uses only the weights and the JPEG
+integers that the benchmark itself made.
 
 The control (``cast=fp8``) rounds every operand of every convolution and
 of the head to float8 e4m3, scaled per tensor, as an fp8 matrix unit
@@ -34,12 +28,8 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from bench import data
-from bench.system import stages
-
-EPS = 1e-5
 
 
 def projector(bands: int) -> np.ndarray:
@@ -61,53 +51,39 @@ def _identity(x):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "control",
+def _frozen(value):
+    """A configuration as a hashable static argument of ``jit``."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _frozen(v)) for k, v in value.items()))
+    if isinstance(value, list):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+@functools.partial(jax.jit, static_argnames=("arch", "layout", "control",
                                              "truncate"))
-def _forward(params, state, x, proj, *, layout, control: bool,
+def _forward(params, state, x, p_b, *, arch, layout, control: bool,
              truncate: bool):
-    cfg = dict(layout)
     cast = fp8 if control else _identity
 
     def p(h):
         if not truncate:
             return h
-        return data.from_blocks(data.to_blocks(h) @ proj)
+        return data.from_blocks(data.to_blocks(h) @ p_b)
 
-    def conv(h, k, s):
-        pad = (k.shape[-1] - 1) // 2
-        return lax.conv_general_dilated(
-            cast(h), cast(k), (s, s), [(pad, pad), (pad, pad)],
-            dimension_numbers=("NCHW", "OIHW", "NCHW"))
-
-    def bn(h, name):
-        inv = params[name]["gamma"] / jnp.sqrt(state[name]["var"] + EPS)
-        shift = params[name]["beta"] - state[name]["mean"] * inv
-        return h * inv[None, :, None, None] + shift[None, :, None, None]
-
-    def relu(h):
-        return p(jnp.maximum(h, 0.0))
-
-    h = relu(p(bn(conv(p(x), params["stem"]["kernel"], 1), "stem_bn")))
-    for name, s, _cin, _w in stages(cfg):
-        blk = params[name]
-        short = p(conv(h, blk["proj"], s)) if "proj" in blk else h
-        a = relu(p(bn(conv(h, blk["conv1"], s), name + "_bn1")))
-        c = p(bn(conv(a, blk["conv2"], 1), name + "_bn2"))
-        h = relu(c + short)
-    pooled = jnp.mean(h, axis=(2, 3))
-    return cast(pooled) @ cast(params["head"]["w"]) + params["head"]["b"]
+    return arch.forward(params, state, x, p, cast, dict(layout))
 
 
-def logits(params, state, cfg: dict, luma, chroma, qtable, *,
+def logits(params, state, cfg: dict, arch, luma, chroma, qtable, *,
            bands: int, control: bool = False, block: int = 32
            ) -> np.ndarray:
-    """Reference logits of the images whose JPEG integers are ``luma``
+    """Reference logits of ``arch`` (the configuration's architecture
+    module) on the images whose JPEG integers are ``luma``
     ``(N, bh, bw, 64)`` and ``chroma`` ``(N, 2, bh, bw, 64)`` under
     ``qtable``, at ``bands``, ``block`` images at a time (the last block
     padded, so that one program serves every block)."""
-    layout = (("widths", tuple(cfg["widths"])),
-              ("blocks_per_stage", cfg["blocks_per_stage"]))
-    proj = jnp.asarray(projector(bands), jnp.float32)
+    layout = _frozen(cfg)
+    p_b = jnp.asarray(projector(bands), jnp.float32)
     out = []
     n = luma.shape[0]
     with jax.default_matmul_precision("highest"):
@@ -120,7 +96,7 @@ def logits(params, state, cfg: dict, luma, chroma, qtable, *,
                 c = np.concatenate([c, np.zeros((pad,) + c.shape[1:],
                                                 c.dtype)])
             x = data.pixels(y, c, qtable)
-            z = _forward(params, state, x, proj, layout=layout,
+            z = _forward(params, state, x, p_b, arch=arch, layout=layout,
                          control=control, truncate=bands < data.NFREQ)
             out.append(np.asarray(z)[: min(block, n - i)])
     return np.concatenate(out)

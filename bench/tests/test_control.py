@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness, system
+from bench import harness, spec, system
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
@@ -27,12 +27,13 @@ def test_control_fails_the_limit(config, traffic, size):
         cfg["image_size"] = size
     mix.update(pool=64, check_sample=64, check_block=32)
     limit = cfg["limits"]["logit_gap"]
+    arch = spec.arch(BENCH / "archs", cfg["arch"])
     answers = [(i, "top", None) for i in range(64)]
     for seed in (3, 2 ** 31 + 17, 4000000007):
         inputs = harness.make_inputs(seed, cfg, mix, {})
-        params, state = system.weights(seed, cfg)
-        got = harness.check(answers, inputs, params, state, cfg, mix, seed,
-                            control=True)
+        params, state = system.weights(seed, cfg, arch)
+        got = harness.check(answers, inputs, params, state, cfg, arch, mix,
+                            seed, control=True)
         assert got["sample"] == 64
         assert got["logit_gap"] > limit, (config, seed, got, limit)
 

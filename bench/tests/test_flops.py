@@ -3,63 +3,90 @@ compiled programs' kernels."""
 import json
 from pathlib import Path
 
+import numpy as np
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import pytest
 
-from bench import flops
+from bench import flops, spec
 
 ROOT = Path(__file__).resolve().parents[2]
 
 
-def _in_bounds_taps(n: int, stride: int, r: int) -> int:
-    """Taps of an ``r``-wide centered window, over one axis of ``n``
-    inputs at ``stride``, that land inside the input."""
-    pad = (r - 1) // 2
-    return sum(0 <= o * stride + t - pad < n
-               for o in range(n // stride) for t in range(r))
+def _in_bounds_macs(jaxpr) -> int:
+    """Multiply-adds of every convolution and matrix product in
+    ``jaxpr`` and the jaxprs inside it, counting only the taps of a
+    convolution that land inside its input."""
+    macs = 0
+    for eqn in jaxpr.eqns:
+        shapes = [v.aval.shape for v in eqn.invars]
+        if eqn.primitive.name == "conv_general_dilated":
+            prm = eqn.params
+            lhs_spec, rhs_spec, out_spec = prm["dimension_numbers"]
+            (lhs, rhs), out = shapes, eqn.outvars[0].aval.shape
+            taps = 1
+            for i, (s, (lo, _hi), d) in enumerate(zip(
+                    prm["window_strides"], prm["padding"],
+                    prm["rhs_dilation"])):
+                n, r = lhs[lhs_spec[2 + i]], rhs[rhs_spec[2 + i]]
+                taps *= sum(0 <= o * s + t * d - lo < n
+                            for o in range(out[out_spec[2 + i]])
+                            for t in range(r))
+            macs += (out[out_spec[0]] * out[out_spec[1]]
+                     * rhs[rhs_spec[1]] * taps)
+        elif eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            macs += int(np.prod(eqn.outvars[0].aval.shape)
+                        * np.prod([shapes[0][d] for d in contract]))
+        for sub in jax.tree_util.tree_leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: isinstance(x, (jcore.Jaxpr,
+                                                 jcore.ClosedJaxpr))):
+            if isinstance(sub, jcore.ClosedJaxpr):
+                macs += _in_bounds_macs(sub.jaxpr)
+            elif isinstance(sub, jcore.Jaxpr):
+                macs += _in_bounds_macs(sub)
+    return macs
 
 
-@pytest.mark.parametrize("name", ["paper-cifar32", "resnet18-256"])
+@pytest.mark.parametrize("name", [c["name"] for c in
+                                  spec.load_benchmark(ROOT)["configs"]])
 def test_model_flops_matches_xla_cost_analysis(name):
-    """``model_flops`` against XLA's count for the program's own spatial
-    network (``core.resnet.spatial_apply``) at the published sizes.  XLA
+    """The architecture module's ``model_flops`` against XLA's count for
+    the program's own spatial network (``core.resnet.spatial_apply`` of
+    the module's ``program_spec``) at the configuration's size.  XLA
     counts only the taps that land inside the image, and the elementwise
     batch norm, ReLU and adds besides; ``model_flops`` counts every tap,
     zero padding included (the usual convention).  So XLA's count lies
-    between the in-bounds multiply-adds and 2% above them."""
+    between the in-bounds multiply-adds, read from the network's own
+    convolutions and products, and 2% above them."""
     from repro.core import resnet as R
-    from bench.system import stages
 
     cfg = json.loads((ROOT / f"bench/configs/{name}.json").read_text())
-    spec = R.ResNetSpec(in_channels=3, widths=tuple(cfg["widths"]),
-                        blocks_per_stage=cfg["blocks_per_stage"],
-                        num_classes=cfg["num_classes"])
+    arch = spec.arch(ROOT / "bench/archs", cfg["arch"])
+    program_spec = arch.program_spec(cfg)
     params, state = jax.eval_shape(
-        lambda k: R.init_resnet(k, spec), jax.random.PRNGKey(0))
+        lambda k: R.init_resnet(k, program_spec), jax.random.PRNGKey(0))
     size = cfg["image_size"]
-    x = jax.ShapeDtypeStruct((1, 3, size, size), jnp.float32)
-    cost = jax.jit(lambda p, s, x: R.spatial_apply(
-        p, s, x, training=False, spec=spec)[0]).lower(
-            params, state, x).cost_analysis()
-    macs = _in_bounds_taps(size, 1, 3) ** 2 * cfg["widths"][0] * 3
-    n = size
-    for _name, s, cin, w in stages(cfg):
-        macs += _in_bounds_taps(n, s, 3) ** 2 * w * cin
-        macs += _in_bounds_taps(n // s, 1, 3) ** 2 * w * w
-        if s != 1 or cin != w:
-            macs += _in_bounds_taps(n, s, 1) ** 2 * w * cin
-        n //= s
-    macs += cfg["widths"][-1] * cfg["num_classes"]
+    x = jax.ShapeDtypeStruct((1, cfg["in_channels"], size, size),
+                             jnp.float32)
+
+    def fn(p, s, x):
+        return R.spatial_apply(p, s, x, training=False, spec=program_spec)[0]
+
+    cost = jax.jit(fn).lower(params, state, x).cost_analysis()
+    macs = _in_bounds_macs(jax.make_jaxpr(fn)(params, state, x).jaxpr)
     assert 2 * macs <= cost["flops"] <= 2 * macs * 1.02
-    assert 2 * macs < flops.model_flops(cfg) < 2 * macs * 1.1
+    assert 2 * macs < arch.model_flops(cfg) < 2 * macs * 1.1
 
 
-def test_published_model_flops():
-    cfg = json.loads((ROOT / "bench/configs/resnet18-256.json").read_text())
-    assert flops.model_flops(cfg) == pytest.approx(71.09e9, rel=1e-3)
-    cfg = json.loads((ROOT / "bench/configs/paper-cifar32.json").read_text())
-    assert flops.model_flops(cfg) == pytest.approx(25.0e6, rel=1e-3)
+@pytest.mark.parametrize("name,want", [("resnet18-256", 71_094_476_800),
+                                       ("paper-cifar32", 25_003_264)])
+def test_published_model_flops(name, want):
+    cfg = json.loads((ROOT / f"bench/configs/{name}.json").read_text())
+    assert spec.arch(ROOT / "bench/archs", cfg["arch"]).model_flops(
+        cfg) == want
 
 
 HLO = """HloModule jit_inner, is_scheduled=true

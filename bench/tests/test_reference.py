@@ -9,22 +9,20 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import data, reference, system
+from bench import data, reference, spec, system
 
 ROOT = Path(__file__).resolve().parents[2]
 CFG = json.loads((ROOT / "bench/configs/paper-cifar32.json").read_text())
+ARCH = spec.arch(ROOT / "bench/archs", CFG["arch"])
 
 
 @pytest.fixture(scope="module")
 def model():
     from repro.core import dispatch as dl
     from repro.core import plan as planlib
-    from repro.core import resnet as R
 
-    params, state = system.weights(2 ** 33 + 5, CFG)
-    spec = R.ResNetSpec(3, tuple(CFG["widths"]), CFG["blocks_per_stage"],
-                        CFG["num_classes"], CFG["quality"], CFG["asm_phi"])
-    plan = planlib.build_plan(params, state, spec,
+    params, state = system.weights(2 ** 33 + 5, CFG, ARCH)
+    plan = planlib.build_plan(params, state, ARCH.program_spec(CFG),
                               dispatch=dl.DispatchConfig(path="reference"))
     return params, state, plan
 
@@ -45,7 +43,7 @@ def test_reference_matches_plan_walk(model, quality, bands):
         got = np.asarray(planlib.apply_plan(
             cap_plan(plan, None if bands == 64 else bands),
             jnp.asarray(coef)))
-    ref = reference.logits(params, state, CFG, luma, chroma, q,
+    ref = reference.logits(params, state, CFG, ARCH, luma, chroma, q,
                            bands=bands, block=4)
     scale = max(1.0, float(np.abs(ref).max()))
     assert np.abs(got - ref).max() / scale < 1e-4
