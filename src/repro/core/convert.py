@@ -109,8 +109,10 @@ def from_torch_layout(tensors: dict[str, Any], spec: resnetlib.ResNetSpec):
 
     Expected names per block: ``<pre>.conv1.weight`` (OIHW), ``<pre>.bn1.
     {weight,bias,running_mean,running_var}``, etc.  Purely a relayout —
-    no numerics.
+    no numerics.  Basic blocks only (raises ``NotImplementedError``
+    otherwise).
     """
+    resnetlib._require_basic(spec, "from_torch_layout")
     params: dict[str, Any] = {}
     state: dict[str, Any] = {}
 
@@ -126,7 +128,8 @@ def from_torch_layout(tensors: dict[str, Any], spec: resnetlib.ResNetSpec):
 
     params["stem"] = {"kernel": jnp.asarray(tensors["stem.weight"])}
     grab_bn("stem_bn", "stem_bn")
-    for name, s, cin, w in resnetlib._stages(spec):
+    for blk in resnetlib._stages(spec):
+        name = blk.name
         entry = {
             "conv1": jnp.asarray(tensors[f"{name}.conv1.weight"]),
             "conv2": jnp.asarray(tensors[f"{name}.conv2.weight"]),

@@ -13,9 +13,12 @@ implementations:
   factors; O(1) extra memory for arbitrarily wide layers).
 
 Selection per call-site is (1) an explicit override — the ``JPEG_DISPATCH``
-env var or :func:`configure`/:func:`override` — then (2) operator size
-(above ``MATERIALIZE_LIMIT`` elements the conv goes factored), then (3)
-backend (pallas on TPU, reference elsewhere).
+env var or :func:`configure`/:func:`override` — then (2) the kernel: a
+1×1 stride-1 convolution is a channel GEMM per coefficient (the
+``pointwise`` route: ``kernels.pointwise_conv`` on TPU, one einsum
+elsewhere), then (3) operator size (above ``MATERIALIZE_LIMIT``
+elements the conv goes factored), then (4) backend (pallas on TPU,
+reference elsewhere).
 
 The ``bands`` knob (paper §6: "the sparsity of the JPEG format allows for
 faster processing") keeps only the first ``bands`` zigzag coefficients.
@@ -48,10 +51,15 @@ __all__ = [
     "PATHS", "DispatchConfig", "get_config", "configure", "override",
     "resolve_config", "register", "lookup", "available_paths", "choose_path",
     "ConvOperator", "conv", "precompute_conv", "apply_conv", "asm_relu",
-    "batchnorm", "block_dct", "block_idct", "fused_block",
+    "batchnorm", "block_dct", "block_idct", "fused_block", "is_pointwise",
+    "pointwise_conv",
 ]
 
+#: paths a configuration may force on every op
 PATHS = ("reference", "pallas", "factored")
+#: every route an op may resolve to: the paths, and the 1x1 stride-1
+#: convolution's channel GEMM, which only ``auto`` resolves to
+ROUTES = PATHS + ("pointwise",)
 
 
 # --------------------------------------------------------------------------
@@ -141,13 +149,13 @@ _REGISTRY: dict[str, dict[str, Callable[..., Any]]] = {}
 
 
 def register(op: str, path: str, fn: Callable[..., Any]) -> None:
-    if path not in PATHS:
+    if path not in ROUTES:
         raise ValueError(f"unknown path {path!r}")
     _REGISTRY.setdefault(op, {})[path] = fn
 
 
 def available_paths(op: str) -> tuple[str, ...]:
-    return tuple(p for p in PATHS if p in _REGISTRY.get(op, {}))
+    return tuple(p for p in ROUTES if p in _REGISTRY.get(op, {}))
 
 
 def lookup(op: str, path: str) -> Callable[..., Any]:
@@ -168,15 +176,29 @@ def _on_tpu() -> bool:
     return platform() == "tpu"
 
 
+def is_pointwise(kernel_shape, stride: int, *, in_scaled: bool = False,
+                 out_scaled: bool = False) -> bool:
+    """Whether a convolution is a channel GEMM per coefficient: a 1×1
+    kernel at stride 1 between orthonormal coefficients (a quantization
+    diagonal folded on either side would scale each coefficient apart)."""
+    return (tuple(kernel_shape[-2:]) == (1, 1) and stride == 1
+            and not in_scaled and not out_scaled)
+
+
 def choose_path(op: str, cfg: DispatchConfig, *,
-                op_elems: int | None = None) -> str:
-    """Resolve 'auto' to a concrete path for one call-site."""
+                op_elems: int | None = None, pointwise: bool = False) -> str:
+    """Resolve 'auto' to a concrete route for one call-site.  A forced
+    path keeps its meaning for every convolution (tests and A/B runs
+    compare the routes); ``auto`` sends a ``pointwise`` one (see
+    :func:`is_pointwise`) to the channel GEMM whatever its size."""
     if cfg.path != "auto":
         if cfg.path == "pallas" and op == "conv" and op_elems is not None \
                 and op_elems > cfg.limit:
             # A forced-pallas Ξ that cannot be materialised must go factored.
             return "factored"
         return cfg.path
+    if op == "conv" and pointwise:
+        return "pointwise"
     if op == "conv" and op_elems is not None and op_elems > cfg.limit:
         return "factored"
     if _on_tpu():
@@ -197,8 +219,11 @@ def _pallas_delegates(cfg: DispatchConfig) -> bool:
 class ConvOperator(NamedTuple):
     """A precomputed layer operator with its resolved apply path.
 
-    ``xi`` is the (possibly band-truncated) materialised Ξ; ``kernel`` is
-    retained for the factored path (which never forms Ξ).  Closure-only:
+    ``xi`` is the (possibly band-truncated) materialised Ξ, or on the
+    ``pointwise`` route the ``(Cout, Cin)`` channel matrix with the batch
+    norm scale folded in (it has no band axis: the route truncates at
+    ``bands`` when it applies); ``kernel`` is retained for the factored
+    path (which never forms Ξ).  Closure-only:
     hold it outside jit arguments (``path``/metadata are not pytree leaves).
 
     ``scale``/``shift`` carry a fused inference-mode batch norm (see
@@ -255,6 +280,29 @@ def _conv_factored(coef, kernel, stride, cfg, *, in_scaled, out_scaled,
         out_scaled=out_scaled, bands=cfg.bands)
 
 
+def pointwise_conv(coef: jnp.ndarray, w: jnp.ndarray,
+                   shift: jnp.ndarray | None, bands: int,
+                   cfg: DispatchConfig) -> jnp.ndarray:
+    """The ``pointwise`` route: ``out[..., o, k] = Σ_c w[o, c]·coef[...,
+    c, k]`` for ``k < bands`` (zero above), plus ``shift`` on DC.  The
+    Pallas kernel on TPU (or with ``interpret``), one einsum elsewhere."""
+    if not _pallas_delegates(cfg):
+        from repro.kernels import ops as kops
+
+        return kops.pointwise_conv(coef, w, shift, bands)
+    nf = coef.shape[-1]
+    out = jnp.einsum("oc,nyxck->nyxok", w, coef[..., :bands])
+    out = convlib.pad_bands(out, nf)
+    if shift is not None:
+        out = out.at[..., 0].add(shift)
+    return out
+
+
+def _conv_pointwise(coef, kernel, stride, cfg, *, in_scaled, out_scaled,
+                    quality):
+    return pointwise_conv(coef, kernel[:, :, 0, 0], None, cfg.bands, cfg)
+
+
 def conv(coef: jnp.ndarray, kernel: jnp.ndarray, stride: int = 1,
          bias: jnp.ndarray | None = None, *, in_scaled: bool = False,
          out_scaled: bool = False, quality: int = 50,
@@ -266,7 +314,8 @@ def conv(coef: jnp.ndarray, kernel: jnp.ndarray, stride: int = 1,
     """
     cfg = resolve_config(cfg)
     path = choose_path("conv", cfg, op_elems=convlib.operator_elems(
-        kernel.shape, stride, cfg.bands))
+        kernel.shape, stride, cfg.bands), pointwise=is_pointwise(
+            kernel.shape, stride, in_scaled=in_scaled, out_scaled=out_scaled))
     out = lookup("conv", path)(coef, kernel, stride, cfg,
                                in_scaled=in_scaled, out_scaled=out_scaled,
                                quality=quality)
@@ -293,10 +342,16 @@ def precompute_conv(kernel: jnp.ndarray, stride: int = 1, *,
     cfg = resolve_config(cfg)
     bands = cfg.bands if bands is None else bands
     path = choose_path("conv", cfg, op_elems=convlib.operator_elems(
-        kernel.shape, stride, bands))
+        kernel.shape, stride, bands), pointwise=is_pointwise(
+            kernel.shape, stride, in_scaled=in_scaled, out_scaled=out_scaled))
     xi = None
     bn_scale = scale
-    if path != "factored":
+    if path == "pointwise":
+        xi = kernel[:, :, 0, 0]
+        if scale is not None:
+            xi = xi * jnp.asarray(scale, xi.dtype)[:, None]
+            scale = None
+    elif path != "factored":
         xi = convlib.explode(kernel, stride, quality=quality,
                              in_scaled=in_scaled, out_scaled=out_scaled,
                              bands=bands)
@@ -337,6 +392,8 @@ def apply_conv(coef: jnp.ndarray, op: ConvOperator,
     output coefficient per channel, ``shift`` adds to DC.
     """
     cfg = resolve_config(cfg)
+    if op.path == "pointwise":
+        return pointwise_conv(coef, op.xi, op.shift, op.bands, cfg)
     out = lookup("conv_apply", op.path)(coef, op, cfg)
     if op.scale is not None:
         out = out * op.scale[None, None, None, :, None]
@@ -495,6 +552,7 @@ def block_idct(coef: jnp.ndarray, quality: int | None = None,
 register("conv", "reference", _conv_reference)
 register("conv", "pallas", _conv_pallas)
 register("conv", "factored", _conv_factored)
+register("conv", "pointwise", _conv_pointwise)
 
 register("conv_apply", "reference", _apply_reference)
 register("conv_apply", "pallas", _apply_pallas)

@@ -145,13 +145,13 @@ def bands_for_profile(profile: np.ndarray, budget: float) -> int:
 
 
 def operator_keys(params: Any, spec: resnetlib.ResNetSpec) -> list[str]:
-    """Flat conv-operator keys in forward order: ``stem``, ``s0b0/conv1``…"""
+    """Flat conv-operator keys in forward order: ``stem``, then per block
+    its projection (if any) and its slots, ``s0b0/conv1``…"""
     keys = ["stem"]
-    for name, s, cin, w in resnetlib._stages(spec):
-        if "proj" in params[name]:
-            keys.append(f"{name}/proj")
-        keys.append(f"{name}/conv1")
-        keys.append(f"{name}/conv2")
+    for blk in resnetlib._stages(spec):
+        if "proj" in params[blk.name]:
+            keys.append(f"{blk.name}/proj")
+        keys += [f"{blk.name}/{sl.name}" for sl in blk.slots]
     return keys
 
 
@@ -215,7 +215,8 @@ def autotune_bands(
 
     def plan_for(assign: dict[str, int]) -> InferencePlan:
         operators: dict[str, Any] = {"stem": ops_for(assign["stem"])["stem"]}
-        for name, s, cin, w in resnetlib._stages(spec):
+        for blk in resnetlib._stages(spec):
+            name = blk.name
             entry = {}
             for slot in ops_for(assign[f"{name}/conv1"])[name]:
                 entry[slot] = ops_for(assign[f"{name}/{slot}"])[name][slot]
@@ -306,17 +307,17 @@ def build_operators(params: Any, spec: resnetlib.ResNetSpec,
             kernel, stride, bands=_resolve_bands(bands, key, cfg),
             scale=scale, shift=shift, cfg=cfg, **kw)
 
-    ops: dict[str, Any] = {"stem": pc("stem", params["stem"]["kernel"], 1,
-                                      in_scaled=True, quality=spec.quality)}
-    for name, s, cin, w in resnetlib._stages(spec):
-        blk = params[name]
-        entry = {
-            "conv1": pc(f"{name}/conv1", blk["conv1"], s),
-            "conv2": pc(f"{name}/conv2", blk["conv2"], 1),
-        }
-        if "proj" in blk:
-            entry["proj"] = pc(f"{name}/proj", blk["proj"], s)
-        ops[name] = entry
+    stem_stride = resnetlib.stem_layout(spec)[1]
+    ops: dict[str, Any] = {"stem": pc("stem", params["stem"]["kernel"],
+                                      stem_stride, in_scaled=True,
+                                      quality=spec.quality)}
+    for blk in resnetlib._stages(spec):
+        p = params[blk.name]
+        entry = {sl.name: pc(f"{blk.name}/{sl.name}", p[sl.name], sl.stride)
+                 for sl in blk.slots}
+        if "proj" in p:
+            entry["proj"] = pc(f"{blk.name}/proj", p["proj"], blk.stride)
+        ops[blk.name] = entry
     return ops
 
 
@@ -333,6 +334,7 @@ def apply_operators(params: Any, state: Any, ops: dict[str, Any],
     a fused batch norm: applying ``state`` on top of them would run BN
     twice and silently corrupt the logits — use :func:`apply_plan`.
     """
+    resnetlib._require_basic(spec, "plan.apply_operators")
     phi = spec.phi if phi is None else phi
     cfg = dispatchlib.resolve_config(cfg)
     stem = ops["stem"]
@@ -354,7 +356,8 @@ def apply_operators(params: Any, state: Any, ops: dict[str, Any],
 
     h = dispatchlib.apply_conv(coef, ops["stem"], cfg=cfg)
     h = relu(bn("stem_bn", h))
-    for name, s, cin, w in resnetlib._stages(spec):
+    for layout in resnetlib._stages(spec):
+        name = layout.name
         blk, op = params[name], ops[name]
         short = h
         if "proj" in blk:
@@ -453,7 +456,8 @@ def build_plan(
 def _fold_all(params: Any, state: Any, spec: resnetlib.ResNetSpec,
               eps: float = 1e-5) -> dict[str, tuple]:
     """(scale, shift) folds for every batch-normed conv, keyed like
-    :func:`operator_keys` (proj convs have no BN and get no entry)."""
+    :func:`operator_keys` (a basic block's proj has no BN and gets no
+    entry)."""
 
     def fold(bn_name):
         p = bnlib.BatchNormParams(params[bn_name]["gamma"],
@@ -463,9 +467,11 @@ def _fold_all(params: Any, state: Any, spec: resnetlib.ResNetSpec,
         return bnlib.fold_batchnorm(p, s, eps=eps)
 
     folds = {"stem": fold("stem_bn")}
-    for name, s, cin, w in resnetlib._stages(spec):
-        folds[f"{name}/conv1"] = fold(name + "_bn1")
-        folds[f"{name}/conv2"] = fold(name + "_bn2")
+    for blk in resnetlib._stages(spec):
+        slots = blk.slots + ((blk.proj,) if blk.proj is not None else ())
+        for sl in slots:
+            if sl.bn is not None:
+                folds[f"{blk.name}/{sl.name}"] = fold(blk.name + sl.bn)
     return folds
 
 
@@ -486,17 +492,20 @@ def apply_plan(plan: InferencePlan, coef: jnp.ndarray,
     h = dispatchlib.apply_conv(coef, ops["stem"], cfg=cfg)
     cur = ops["stem"].bands
     h = relu(h, cur)
+    if resnetlib.stem_layout(plan.spec)[2]:
+        h = poollib.max_pool_jpeg(h, cur)
     h = shard(h, "batch", None, None, None, None)
-    for name, s, cin, w in resnetlib._stages(plan.spec):
-        op = ops[name]
+    for blk in resnetlib._stages(plan.spec):
+        op = ops[blk.name]
         short, short_bands = h, cur
         if "proj" in op:
             short = dispatchlib.apply_conv(h, op["proj"], cfg=cfg)
             short_bands = op["proj"].bands
-        h = dispatchlib.apply_conv(h, op["conv1"], cfg=cfg)
-        h = relu(h, op["conv1"].bands)
-        h = dispatchlib.apply_conv(h, op["conv2"], cfg=cfg)
-        cur = max(op["conv2"].bands, short_bands)
+        for sl in blk.slots:
+            h = dispatchlib.apply_conv(h, op[sl.name], cfg=cfg)
+            if sl.relu_after:
+                h = relu(h, op[sl.name].bands)
+        cur = max(op[blk.slots[-1].name].bands, short_bands)
         h = relu(poollib.residual_add(h, short), cur)
         h = shard(h, "batch", None, None, None, None)
     pooled = poollib.global_avg_pool_jpeg(h)
@@ -597,12 +606,17 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
                  image_size: int | None = None) -> CompiledPlan:
     """Lower a plan into the fused static schedule.
 
-    Per residual block: pack conv1/conv2 (and the projection shortcut) at
-    their own sublane-aligned per-channel band widths; the executors fit
-    the activation between stages with elementwise lane slices/pads.
-    Blocks whose operators are factored (never materialised Ξ) or — on
-    the pallas path — whose VMEM estimate exceeds ``vmem_budget`` stay on
-    the per-layer walk.  On a TPU the fused path resolves to ``"gemm"``
+    Per basic residual block: pack conv1/conv2 (and the projection
+    shortcut) at their own sublane-aligned per-channel band widths; the
+    executors fit the activation between stages with elementwise lane
+    slices/pads.  Blocks whose operators are factored (never materialised
+    Ξ) or — on the pallas path — whose VMEM estimate exceeds
+    ``vmem_budget`` stay on the per-layer walk, and so does every
+    bottleneck block (the packed-GEMM fused path takes basic blocks
+    only).  ``meta["routes"]`` counts the convolutions by route
+    (``pointwise``, ``factored``, ``materialised``) and
+    ``meta["pointwise"]`` names what runs the pointwise ones.  On a TPU
+    the fused path resolves to ``"gemm"``
     (the packed-GEMM twin, ``MEGAKERNEL_OFF_TPU``); ``meta["platform"]``
     records the backend the paths were resolved on.
 
@@ -627,7 +641,11 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
         rules["megakernel"] = MEGAKERNEL_OFF_TPU
     meta: dict[str, Any] = {"fused": [], "layers": {}, "vmem": {},
                             "budget": int(vmem_budget), "path": path,
-                            "platform": platform, "rules": rules}
+                            "platform": platform, "rules": rules,
+                            "routes": _route_counts(plan)}
+    if meta["routes"]["pointwise"]:
+        meta["pointwise"] = ("einsum" if dispatchlib._pallas_delegates(cfg)
+                             else "pointwise_conv_pallas")
 
     st = plan.operators["stem"]
     cout0 = st.kernel.shape[0]
@@ -648,20 +666,24 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
     # halving at each stride-2 stage
     if image_size is None:
         image_size = dctlib.BLOCK * 2 ** (len(spec.widths) - 1)
-    bh = max(1, image_size // dctlib.BLOCK)
+    _r, stem_stride, pool = resnetlib.stem_layout(spec)
+    bh = max(1, image_size // dctlib.BLOCK // stem_stride // (2 if pool
+                                                                else 1))
     cur_b, cur_w = stem.bands_out, stem.w_out
     blocks = []
-    for name, s, cin, w in resnetlib._stages(spec):
+    for layout in resnetlib._stages(spec):
+        name, s, cin, w = layout.name, layout.stride, layout.cin, layout.cout
         entry = plan.operators[name]
-        c1, c2 = entry["conv1"], entry["conv2"]
+        last = entry[layout.slots[-1].name]
         pr = entry.get("proj")
         short_b = pr.bands if pr is not None else cur_b
-        j_true = max(c2.bands, short_b)
-        convs = [c1, c2] + ([pr] if pr is not None else [])
-        materialized = all(op.xi is not None for op in convs)
-
+        j_true = max(last.bands, short_b)
         blk = None
-        if materialized:
+        if spec.block != "basic":
+            meta["layers"][name] = ("bottleneck block: the packed-GEMM fused "
+                                    "path takes basic blocks only")
+        elif all(op.xi is not None for op in entry.values()):
+            c1, c2 = entry["conv1"], entry["conv2"]
             # every operand at its *own* true (sublane-rounded) band width
             # — the fused executor fits the activation between stages with
             # elementwise lane slices/pads, so a wide residual join never
@@ -704,6 +726,18 @@ def compile_plan(plan: InferencePlan, *, vmem_budget: int = VMEM_BUDGET,
         bh = max(1, bh // s)
     return CompiledPlan(stem, tuple(blocks), plan.head_w, plan.head_b,
                         spec, phi, cfg, dict(plan.bands), meta)
+
+
+def _route_counts(plan: InferencePlan) -> dict[str, int]:
+    """Convolutions of ``plan`` by route: ``pointwise`` (the channel
+    GEMM), ``factored`` (decode, spatial conv, encode) or
+    ``materialised`` (a Ξ applied by the reference or Pallas path)."""
+    counts = {"pointwise": 0, "factored": 0, "materialised": 0}
+    for op in _flat_ops(plan).values():
+        route = op.path if op.path in ("pointwise", "factored") \
+            else "materialised"
+        counts[route] += 1
+    return counts
 
 
 def _repack_width(h: jnp.ndarray, c: int, w_to: int) -> jnp.ndarray:
@@ -767,29 +801,45 @@ def _apply_stem(stem: CompiledStem, coef: jnp.ndarray, phi: int, path: str,
         return fblib.fused_stem_spatial(coef, stem.op, phi, stem.w_out)
     h = dispatchlib.apply_conv(coef, stem.op, cfg=cfg)
     h = dispatchlib.asm_relu(h, phi, cfg=cfg, bands=stem.bands_out)
-    return h[..., : stem.w_out].reshape(n, bh, bw, stem.cout * stem.w_out)
+    return h[..., : stem.w_out].reshape(*h.shape[:3],
+                                        stem.cout * stem.w_out)
 
 
-def _apply_layers_block(blk: CompiledBlock, h: jnp.ndarray, phi: int,
+def _stem_pool(h: jnp.ndarray, stem: CompiledStem) -> jnp.ndarray:
+    """The stem's max-pool on its packed output (``7x7s2-maxpool``)."""
+    n, bh, bw, _ = h.shape
+    x = poollib.max_pool_jpeg(h.reshape(n, bh, bw, stem.cout, stem.w_out),
+                              stem.bands_out)
+    return x.reshape(n, bh // 2, bw // 2, stem.cout * stem.w_out)
+
+
+def _apply_layers_block(blk: CompiledBlock, layout: resnetlib.BlockLayout,
+                        h: jnp.ndarray, phi: int,
                         cfg: dispatchlib.DispatchConfig) -> jnp.ndarray:
     """Per-layer fallback: unpack to the 64-wide layout, run the exact
-    ``apply_plan`` block body, repack to the scheduled output width."""
+    ``apply_plan`` block body (each convolution under its slot's name),
+    repack to the scheduled output width."""
     from repro.core.conv import pad_bands
 
     n, bh, bw, _ = h.shape
     ops = blk.ops
-    s = ops["conv1"].stride
+    s = layout.stride
     h64 = pad_bands(h.reshape(n, bh, bw, blk.cin, blk.w_in))
     short, short_b = h64, blk.bands_in
     if "proj" in ops:
-        short = dispatchlib.apply_conv(h64, ops["proj"], cfg=cfg)
+        with jax.named_scope("proj"):
+            short = dispatchlib.apply_conv(h64, ops["proj"], cfg=cfg)
         short_b = ops["proj"].bands
-    x = dispatchlib.apply_conv(h64, ops["conv1"], cfg=cfg)
-    x = dispatchlib.asm_relu(x, phi, cfg=cfg, bands=ops["conv1"].bands)
-    x = dispatchlib.apply_conv(x, ops["conv2"], cfg=cfg)
+    x = h64
+    for sl in layout.slots:
+        with jax.named_scope(sl.name):
+            x = dispatchlib.apply_conv(x, ops[sl.name], cfg=cfg)
+            if sl.relu_after:
+                x = dispatchlib.asm_relu(x, phi, cfg=cfg,
+                                         bands=ops[sl.name].bands)
     x = poollib.residual_add(x, short)
-    x = dispatchlib.asm_relu(x, phi, cfg=cfg,
-                             bands=max(ops["conv2"].bands, short_b))
+    x = dispatchlib.asm_relu(
+        x, phi, cfg=cfg, bands=max(ops[layout.slots[-1].name].bands, short_b))
     return x[..., : blk.w_out].reshape(n, bh // s, bw // s,
                                        blk.cout * blk.w_out)
 
@@ -950,8 +1000,8 @@ def jit_over(tree: Any, fn, **jit_kw):
     return call
 
 
-def _make_block_fn(blk: CompiledBlock, w_prev: int, phi: int,
-                   cfg: dispatchlib.DispatchConfig,
+def _make_block_fn(blk: CompiledBlock, layout: resnetlib.BlockLayout,
+                   w_prev: int, phi: int, cfg: dispatchlib.DispatchConfig,
                    executor: str | None):
     """One schedule step: (optional width repack into the block, then)
     the fused/fallback block body, then the batch-axis shard hint."""
@@ -969,7 +1019,7 @@ def _make_block_fn(blk: CompiledBlock, w_prev: int, phi: int,
                 h = dispatchlib.fused_block(h, blk, phi, path=blk.path,
                                             cfg=cfg)
         else:
-            h = _apply_layers_block(blk, h, phi, cfg)
+            h = _apply_layers_block(blk, layout, h, phi, cfg)
         return shard(h, "batch", None, None, None)
 
     return fn
@@ -1023,6 +1073,7 @@ def compiled_steps(cp: CompiledPlan,
     cfg = cp.cfg if cfg is None else cfg
     path = (cp.meta or {}).get("path", "reference")
     st = cp.stem
+    pool = resnetlib.stem_layout(cp.spec)[2]
 
     def stem_fn(x):
         if packed:
@@ -1047,13 +1098,17 @@ def compiled_steps(cp: CompiledPlan,
                 h = _apply_stem(st, coef, cp.phi, path, cfg, executor)
         else:
             h = _apply_stem(st, x, cp.phi, path, cfg, executor)
+        if pool:
+            with jax.named_scope("maxpool"):
+                h = _stem_pool(h, st)
         return shard(h, "batch", None, None, None)
 
+    layouts = {b.name: b for b in resnetlib._stages(cp.spec)}
     steps = [("stem", stem_fn)]
     cur_w = st.w_out
     for blk in cp.blocks:
-        steps.append((blk.name, _make_block_fn(blk, cur_w, cp.phi, cfg,
-                                               executor)))
+        steps.append((blk.name, _make_block_fn(blk, layouts[blk.name], cur_w,
+                                               cp.phi, cfg, executor)))
         cur_w = blk.w_out
     steps.append(("head", _make_head_fn(cp, cur_w)))
     return [(name, _named(name, fn)) for name, fn in steps]
@@ -1222,8 +1277,7 @@ def load_plan(directory: str, step: int | None = None) -> InferencePlan:
     def arr(key):
         return jnp.asarray(by_path[_leaf_path(key)])
 
-    spec_d = dict(extra["spec"], widths=tuple(extra["spec"]["widths"]))
-    spec = resnetlib.ResNetSpec(**spec_d)
+    spec = resnetlib.spec_from_dict(extra["spec"])
     cfg = dispatchlib.DispatchConfig(**extra["cfg"])
     operators: dict[str, Any] = {}
     for key, meta in extra["ops"].items():
@@ -1364,9 +1418,9 @@ def load_compiled_plan(directory: str, step: int | None = None
                 int(m["vmem_bytes"])))
         else:
             blocks.append(CompiledBlock(*common, ops=ops))
-    spec_d = dict(extra["spec"], widths=tuple(extra["spec"]["widths"]))
     return CompiledPlan(stem, tuple(blocks), arr("head.w"), arr("head.b"),
-                        resnetlib.ResNetSpec(**spec_d), int(extra["phi"]),
+                        resnetlib.spec_from_dict(extra["spec"]),
+                        int(extra["phi"]),
                         dispatchlib.DispatchConfig(**extra["cfg"]),
                         {k: int(v) for k, v in extra["bands"].items()},
                         extra.get("meta"))

@@ -13,10 +13,22 @@ JPEG checkpoint.  ``precompute_operators`` bakes the exploded Ξ operators
 for inference so each step is matmuls only (the paper's "the map can be
 precomputed to speed up inference").
 
-Architecture (paper Fig. 3, generalised): a stem conv, then ``len(widths)``
-stages of ``blocks_per_stage`` basic residual blocks; every stage after the
+Architecture (paper Fig. 3, generalised): a stem, then ``len(widths)``
+stages of ``blocks_per_stage`` residual blocks; every stage after the
 first downsamples by 2 so a 32×32 input with 3 stages ends at a single JPEG
-block; global average pool; linear classifier.
+block; global average pool; linear classifier.  Two block kinds:
+
+* ``"basic"`` (the paper's): 3×3/s → ReLU → 3×3, ReLU after the add;
+* ``"bottleneck"`` (ResNet-50 v1.5, arXiv:1512.03385 Table 1 with the
+  stride on the 3×3 as torchvision's ``resnet50``): 1×1 → ReLU → 3×3/s →
+  ReLU → 1×1 to ``4·width`` channels, ReLU after the add; the projection
+  shortcut carries a batch norm.
+
+and two stems: ``"3x3"`` (the paper's 3×3 stride-1 convolution) and
+``"7x7s2-maxpool"`` (ResNet's 7×7 stride-2 convolution, then a 3×3/2
+max-pool — not in the paper).  :func:`_stages` describes every block as
+an ordered list of convolution slots; the parameter pytree, the spatial
+oracle and the plan code (``core.plan``) all walk those slots.
 """
 from __future__ import annotations
 
@@ -45,13 +57,89 @@ __all__ = [
 ]
 
 
+BLOCKS = ("basic", "bottleneck")
+STEMS = ("3x3", "7x7s2-maxpool")
+#: output channels of a bottleneck block per unit of its inner width
+EXPANSION = 4
+
+
 class ResNetSpec(NamedTuple):
     in_channels: int = 3
     widths: tuple[int, ...] = (16, 32, 64)
-    blocks_per_stage: int = 1
+    #: one count for every stage, or one count per stage
+    blocks_per_stage: int | tuple[int, ...] = 1
     num_classes: int = 10
     quality: int = 50  # quantization table the input coefficients use
     phi: int = asmlib.EXACT_PHI  # ASM ReLU spatial frequencies
+    block: str = "basic"
+    stem: str = "3x3"
+
+
+class ConvSlot(NamedTuple):
+    """One convolution of a block, in forward order.  ``relu_after``: an
+    ASM ReLU follows it directly (the last slot's ReLU follows the
+    residual add instead); ``bn``: the suffix of its batch norm's
+    parameter name (``<block><bn>``), None where it has none."""
+
+    name: str
+    kernel: int
+    stride: int
+    cin: int
+    cout: int
+    relu_after: bool
+    bn: str | None
+
+
+class BlockLayout(NamedTuple):
+    """One residual block: its convolution ``slots`` in order and the
+    ``proj`` shortcut (a 1×1 convolution at the block's stride) where the
+    shape changes, else None."""
+
+    name: str
+    stride: int
+    cin: int
+    cout: int
+    slots: tuple[ConvSlot, ...]
+    proj: ConvSlot | None
+
+
+def stage_counts(spec: ResNetSpec) -> tuple[int, ...]:
+    """Blocks in each stage."""
+    bps = spec.blocks_per_stage
+    if isinstance(bps, int):
+        return (bps,) * len(spec.widths)
+    if len(bps) != len(spec.widths):
+        raise ValueError(f"blocks_per_stage {tuple(bps)} does not give one "
+                         f"count per stage of widths {spec.widths}")
+    return tuple(int(b) for b in bps)
+
+
+def stem_layout(spec: ResNetSpec) -> tuple[int, int, bool]:
+    """``(kernel, stride, max_pool)`` of the stem."""
+    if spec.stem == "3x3":
+        return 3, 1, False
+    if spec.stem == "7x7s2-maxpool":
+        return 7, 2, True
+    raise ValueError(f"unknown stem {spec.stem!r} (have {STEMS})")
+
+
+def spec_from_dict(d: dict) -> ResNetSpec:
+    """A spec from its ``_asdict()`` after a JSON round trip (lists back
+    to tuples)."""
+    d = dict(d, widths=tuple(d["widths"]))
+    if isinstance(d.get("blocks_per_stage"), list):
+        d["blocks_per_stage"] = tuple(d["blocks_per_stage"])
+    return ResNetSpec(**d)
+
+
+def _require_basic(spec: ResNetSpec, route: str) -> None:
+    """The unserved routes implement the paper's network only; they must
+    not compute something else for any other."""
+    if spec.block != "basic" or spec.stem != "3x3":
+        raise NotImplementedError(
+            f"{route} runs basic blocks with the 3x3 stem only; serve a "
+            f"{spec.block!r} / {spec.stem!r} spec through plan.build_plan "
+            f"-> plan.compile_plan")
 
 
 def _conv_init(key, cout, cin, r, dtype):
@@ -61,7 +149,7 @@ def _conv_init(key, cout, cin, r, dtype):
 
 def init_resnet(key: jax.Array, spec: ResNetSpec, dtype=jnp.float32):
     """Returns ``(params, state)`` pytrees shared by both domains."""
-    keys = iter(jax.random.split(key, 4 + 4 * len(spec.widths) * spec.blocks_per_stage))
+    keys = iter(jax.random.split(key, 4 + 4 * sum(stage_counts(spec))))
     params: dict[str, Any] = {}
     state: dict[str, Any] = {}
 
@@ -70,23 +158,22 @@ def init_resnet(key: jax.Array, spec: ResNetSpec, dtype=jnp.float32):
         params[name] = {"gamma": p.gamma, "beta": p.beta}
         state[name] = {"mean": s.running_mean, "var": s.running_var}
 
-    params["stem"] = {"kernel": _conv_init(next(keys), spec.widths[0], spec.in_channels, 3, dtype)}
+    def conv(slot):
+        return _conv_init(next(keys), slot.cout, slot.cin, slot.kernel, dtype)
+
+    r = stem_layout(spec)[0]
+    params["stem"] = {"kernel": _conv_init(next(keys), spec.widths[0], spec.in_channels, r, dtype)}
     bn("stem_bn", spec.widths[0])
     cin = spec.widths[0]
-    for si, w in enumerate(spec.widths):
-        stride = 1 if si == 0 else 2
-        for bi in range(spec.blocks_per_stage):
-            pre = f"s{si}b{bi}"
-            s = stride if bi == 0 else 1
-            params[pre] = {
-                "conv1": _conv_init(next(keys), w, cin, 3, dtype),
-                "conv2": _conv_init(next(keys), w, w, 3, dtype),
-            }
-            bn(pre + "_bn1", w)
-            bn(pre + "_bn2", w)
-            if s != 1 or cin != w:
-                params[pre]["proj"] = _conv_init(next(keys), w, cin, 1, dtype)
-            cin = w
+    for blk in _stages(spec):
+        params[blk.name] = {sl.name: conv(sl) for sl in blk.slots}
+        for sl in blk.slots:
+            bn(blk.name + sl.bn, sl.cout)
+        if blk.proj is not None:
+            params[blk.name]["proj"] = conv(blk.proj)
+            if blk.proj.bn is not None:
+                bn(blk.name + blk.proj.bn, blk.cout)
+        cin = blk.cout
     params["head"] = {
         "w": jax.random.normal(next(keys), (cin, spec.num_classes), dtype)
         * np.sqrt(1.0 / cin),
@@ -96,13 +183,26 @@ def init_resnet(key: jax.Array, spec: ResNetSpec, dtype=jnp.float32):
 
 
 def _stages(spec: ResNetSpec):
+    """Every residual block as a :class:`BlockLayout`, in forward order."""
+    if spec.block not in BLOCKS:
+        raise ValueError(f"unknown block {spec.block!r} (have {BLOCKS})")
     cin = spec.widths[0]
-    for si, w in enumerate(spec.widths):
-        stride = 1 if si == 0 else 2
-        for bi in range(spec.blocks_per_stage):
-            s = stride if bi == 0 else 1
-            yield f"s{si}b{bi}", s, cin, w
-            cin = w
+    for si, (w, count) in enumerate(zip(spec.widths, stage_counts(spec))):
+        for bi in range(count):
+            s = 2 if si and not bi else 1
+            if spec.block == "basic":
+                cout, proj_bn = w, None
+                slots = (ConvSlot("conv1", 3, s, cin, w, True, "_bn1"),
+                         ConvSlot("conv2", 3, 1, w, w, False, "_bn2"))
+            else:
+                cout, proj_bn = EXPANSION * w, "_bnp"
+                slots = (ConvSlot("conv1", 1, 1, cin, w, True, "_bn1"),
+                         ConvSlot("conv2", 3, s, w, w, True, "_bn2"),
+                         ConvSlot("conv3", 1, 1, w, cout, False, "_bn3"))
+            proj = (ConvSlot("proj", 1, s, cin, cout, False, proj_bn)
+                    if s != 1 or cin != cout else None)
+            yield BlockLayout(f"s{si}b{bi}", s, cin, cout, slots, proj)
+            cin = cout
 
 
 # --------------------------------------------------------------------------
@@ -121,17 +221,23 @@ def spatial_apply(params, state, x, *, training: bool, spec: ResNetSpec):
         new_state[name] = {"mean": s2.running_mean, "var": s2.running_var}
         return h
 
-    h = convlib.spatial_conv(x, params["stem"]["kernel"], 1)
+    _r, stem_stride, pool = stem_layout(spec)
+    h = convlib.spatial_conv(x, params["stem"]["kernel"], stem_stride)
     h = jax.nn.relu(bn("stem_bn", h))
-    for name, s, cin, w in _stages(spec):
-        blk = params[name]
+    if pool:
+        h = poollib.max_pool_spatial(h)
+    for blk in _stages(spec):
+        p = params[blk.name]
         short = h
-        if "proj" in blk:
-            short = convlib.spatial_conv(h, blk["proj"], s)
-        h = convlib.spatial_conv(h, blk["conv1"], s)
-        h = jax.nn.relu(bn(name + "_bn1", h))
-        h = convlib.spatial_conv(h, blk["conv2"], 1)
-        h = bn(name + "_bn2", h)
+        if blk.proj is not None:
+            short = convlib.spatial_conv(h, p["proj"], blk.stride)
+            if blk.proj.bn is not None:
+                short = bn(blk.name + blk.proj.bn, short)
+        for sl in blk.slots:
+            h = bn(blk.name + sl.bn, convlib.spatial_conv(h, p[sl.name],
+                                                          sl.stride))
+            if sl.relu_after:
+                h = jax.nn.relu(h)
         h = jax.nn.relu(h + short)
     pooled = poollib.global_avg_pool_spatial(h)
     logits = pooled @ params["head"]["w"] + params["head"]["b"]
@@ -158,6 +264,7 @@ def jpeg_apply(params, state, coef, *, training: bool, spec: ResNetSpec,
     ``dispatch``: per-op backend/band policy (None = the global config,
     see ``core.dispatch``).  Resolved at trace time.
     """
+    _require_basic(spec, "jpeg_apply")
     phi = spec.phi if phi is None else phi
     cfg = dispatchlib.resolve_config(dispatch)
     new_state = {}
@@ -179,7 +286,8 @@ def jpeg_apply(params, state, coef, *, training: bool, spec: ResNetSpec,
                          in_scaled=True, quality=spec.quality, cfg=cfg)
     h = relu(bn("stem_bn", h))
     h = shard(h, "batch", None, None, None, None)
-    for name, s, cin, w in _stages(spec):
+    for layout in _stages(spec):
+        name, s = layout.name, layout.stride
 
         def block_fn(h, blk, bn1p, bn1s, bn2p, bn2s):
             short = h
@@ -240,6 +348,7 @@ def jpeg_apply_precomputed(params, state, ops, coef, *, spec: ResNetSpec,
     """
     from repro.core import plan as planlib
 
+    _require_basic(spec, "jpeg_apply_precomputed")
     return planlib.apply_operators(params, state, ops, coef, spec=spec,
                                    phi=phi, cfg=dispatch)
 

@@ -15,9 +15,11 @@ from repro.kernels.block_dct import block_dct_pallas, block_idct_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_block import fused_block_pallas
 from repro.kernels.jpeg_conv import jpeg_conv_pallas
+from repro.kernels.pointwise_conv import pointwise_conv_pallas
 
 __all__ = ["interpret_default", "asm_relu", "block_dct", "block_idct",
-           "jpeg_conv_apply", "fused_block", "flash_attention"]
+           "jpeg_conv_apply", "pointwise_conv", "fused_block",
+           "flash_attention"]
 
 
 def interpret_default() -> bool:
@@ -59,6 +61,18 @@ def jpeg_conv_apply(coef: jnp.ndarray, xi: jnp.ndarray,
                     stride: int = 1) -> jnp.ndarray:
     """Pallas twin of ``core.conv.apply_exploded``."""
     return jpeg_conv_pallas(coef, xi, stride, interpret=interpret_default())
+
+
+def pointwise_conv(coef: jnp.ndarray, w: jnp.ndarray,
+                   shift: jnp.ndarray | None, bands: int) -> jnp.ndarray:
+    """Pallas twin of the ``pointwise`` route over ``(..., Cin, nf)``
+    coefficients: the leading axes flatten to blocks (a free reshape of
+    the row-major layout)."""
+    *lead, cin, nf = coef.shape
+    out = pointwise_conv_pallas(coef.reshape(-1, cin, nf), w, shift,
+                                bands=min(bands, nf),
+                                interpret=interpret_default())
+    return out.reshape(*lead, w.shape[0], nf)
 
 
 def fused_block(x: jnp.ndarray, conv1, asm_mid, conv2, asm_out,

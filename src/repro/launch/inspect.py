@@ -71,6 +71,9 @@ def run_inspect(args) -> dict:
           f"({'built' if plan_info['built'] else 'restored'}), "
           f"{len(plan_info.get('fused_blocks', []))} fused blocks, "
           f"executor={executor or 'plan'}, hw={hw.name}")
+    print(f"[inspect] convolutions by route {compiled.meta.get('routes')}"
+          + (f", pointwise on {compiled.meta['pointwise']}"
+             if compiled.meta.get("pointwise") else ""))
     report = introspect.predicted_vs_measured(
         compiled, coef, executor=executor, hw=hw, iters=args.iters,
         warmup=args.warmup)

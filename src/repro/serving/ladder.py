@@ -102,14 +102,15 @@ def cap_operator(op, cap: int):
 
     Bit-exact vs re-exploding at the capped band count (the basis
     truncation *is* this slice); factored operators (no materialised Ξ)
-    just lower their ``bands`` field — their apply path truncates by
-    zeroing at run time.
+    and pointwise ones (a channel matrix with no band axis) just lower
+    their ``bands`` field — their apply path slices the coefficients to
+    it at run time.
     """
     b = min(op.bands, cap)
     if b == op.bands:
         return op
     xi = op.xi
-    if xi is not None:
+    if xi is not None and op.path != "pointwise":
         xi = xi[:, :, :, :b, :, :b]
     return op._replace(xi=xi, bands=b)
 

@@ -90,7 +90,8 @@ def test_from_torch_layout(setup):
         tensors[f"{name}.bias"] = np.asarray(params[name]["beta"])
         tensors[f"{name}.running_mean"] = np.asarray(state[name]["mean"])
         tensors[f"{name}.running_var"] = np.asarray(state[name]["var"])
-    for name, s, cin, w in R._stages(spec):
+    for blk in R._stages(spec):
+        name = blk.name
         tensors[f"{name}.conv1.weight"] = np.asarray(params[name]["conv1"])
         tensors[f"{name}.conv2.weight"] = np.asarray(params[name]["conv2"])
         if "proj" in params[name]:

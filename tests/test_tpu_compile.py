@@ -26,6 +26,7 @@ from repro.configs.base import get_config, reduced_config
 from repro.core import conv as C
 from repro.kernels.asm_relu import asm_relu_pallas
 from repro.kernels.jpeg_conv import jpeg_conv_pallas
+from repro.kernels.pointwise_conv import pointwise_conv_pallas
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +116,20 @@ def test_factored_conv_compiles_without_loops(one_chip, name, grid, cin,
                       _spec((cout, cin, r, r), one_chip)).compile().as_text()
     assert "while(" not in text
     assert "gather(" not in text
+
+
+@pytest.mark.parametrize("blocks,cin,cout,bands", [
+    (32 * 8 * 8, 64, 256, 64),     # ResNet-50 s0 conv3 / s0b0 proj, batch 32
+    (32 * 8 * 8, 256, 64, 64),     # s0 conv1
+    (32 * 4 * 4, 512, 128, 32),    # s1 conv1 at the b32 tier
+    (32 * 1 * 1, 2048, 512, 64),   # s3 conv1
+    (32 * 1 * 1, 512, 2048, 64),   # s3 conv3
+], ids=["r50-s0-conv3", "r50-s0-conv1", "r50-s1-conv1-b32", "r50-s3-conv1",
+        "r50-s3-conv3"])
+def test_pointwise_conv_compiles_for_v5e(one_chip, blocks, cin, cout, bands):
+    """ResNet-50's 1x1 stride-1 convolutions at its published widths, on
+    the packed ``(N·bh·bw, C, 64)`` activation of a batch of 32."""
+    compiled = pointwise_conv_pallas.lower(
+        _spec((blocks, cin, 64), one_chip), _spec((cout, cin), one_chip),
+        _spec((cout,), one_chip), bands=bands, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
