@@ -123,25 +123,33 @@ def cover_buckets(buckets, batch: int) -> tuple[int, ...]:
 class PinnedPool:
     """Reusable host staging buffers, keyed by (shape, dtype).
 
-    One buffer per distinct full-batch shape, shared by every cell that
-    stages through it — the grid has one dispatching thread (the
-    scheduler worker), so sharing is safe and keeps host memory at
-    O(distinct shapes), not O(cells).
+    Two buffers per distinct full-batch shape, one for each of the
+    scheduler's two batches in flight (``slot`` 0 and 1), shared by every
+    cell that stages through it.  JAX may hand a numpy argument to the
+    host->device copy without copying it, so the copy can still be
+    reading a buffer after ``jnp.array`` returns: the batch launched
+    behind a running one stages into the other slot's buffer, and a slot
+    takes a new batch only after its last one was read back.  The grid
+    has one dispatching thread (the scheduler worker), so sharing is
+    safe and keeps host memory at O(distinct shapes), not O(cells).
     """
 
-    def __init__(self) -> None:
-        self._bufs: dict[tuple, np.ndarray] = {}
+    SLOTS = 2
 
-    def get(self, shape, dtype=np.float32) -> np.ndarray:
+    def __init__(self) -> None:
+        self._bufs: dict[tuple, tuple[np.ndarray, ...]] = {}
+
+    def get(self, shape, dtype=np.float32, slot: int = 0) -> np.ndarray:
         key = (tuple(int(s) for s in shape), np.dtype(dtype))
-        buf = self._bufs.get(key)
-        if buf is None:
-            buf = self._bufs[key] = np.zeros(key[0], key[1])
-        return buf
+        bufs = self._bufs.get(key)
+        if bufs is None:
+            bufs = self._bufs[key] = tuple(np.zeros(key[0], key[1])
+                                           for _ in range(self.SLOTS))
+        return bufs[slot]
 
     @property
     def nbytes(self) -> int:
-        return sum(b.nbytes for b in self._bufs.values())
+        return sum(b.nbytes for bufs in self._bufs.values() for b in bufs)
 
     def __len__(self) -> int:
         return len(self._bufs)
@@ -150,10 +158,10 @@ class PinnedPool:
 class GridCell:
     """One (kind, bucket) executor of a grid column.
 
-    ``__call__`` stages up to ``bucket`` rows into the pooled pinned
-    buffer (zero-filling the pad tail), copies it to device
-    (``jnp.array`` always copies — the staging buffer stays reusable
-    while the fresh device buffer is donated into the executable), and
+    ``__call__`` stages up to ``bucket`` rows into its slot's pooled
+    pinned buffer (zero-filling the pad tail), copies it to a fresh
+    device buffer (donated into the executable, so the staging buffer
+    stays reusable), and
     returns the logits for all ``bucket`` slots; callers slice off the
     first ``n``.  ``hits`` counts dispatches for the metrics report.
     """
@@ -176,7 +184,8 @@ class GridCell:
         self._packed = bool(packed)
         self.hits = 0
 
-    def __call__(self, rows: np.ndarray, rids=None) -> jnp.ndarray:
+    def __call__(self, rows: np.ndarray, rids=None, slot: int = 0,
+                 behind: int = 0) -> jnp.ndarray:
         rows = np.asarray(rows, np.float32)
         n = rows.shape[0]
         if n > self.bucket or tuple(rows.shape[1:]) != self.item_shape:
@@ -187,23 +196,27 @@ class GridCell:
         # nested under the scheduler's device-dispatch span: the
         # host-staging + host->device copy share of the dispatch, then
         # the executable's launch (asynchronous: it returns before the
-        # device is done)
-        with tr.scope("device", "pad/stage",
+        # device is done); ``slot`` is the batch's staging buffer and
+        # trace row, and the launch span's ``behind`` counts batches
+        # launched and not yet read when this one launched
+        with tr.scope("device", "pad/stage", tid=slot,
                       args={"cell": self.name, "n": n,
                             "pad": self.bucket - n, "rids": rids}
                       if tr.enabled else None):
-            host = self._pool.get(self._shape)
+            host = self._pool.get(self._shape, slot=slot)
             host[:n] = rows
             if n < self.bucket:
                 host[n:] = 0.0
             dev = jnp.array(host)
         self.hits += 1
-        with tr.scope("device", "launch"):
+        with tr.scope("device", "launch", tid=slot,
+                      args={"behind": behind} if tr.enabled else None):
             return self._fn(dev)
 
     def warmup(self) -> None:
-        host = self._pool.get(self._shape)
-        host[:] = 0.0
+        for slot in range(self._pool.SLOTS):  # touch every page
+            host = self._pool.get(self._shape, slot=slot)
+            host[:] = 0.0
         self._fn(jnp.array(host)).block_until_ready()
 
     def lower(self):
@@ -346,22 +359,27 @@ class GridColumn:
                                            packed=(kind == "bytes"))
         return c
 
-    def _route(self, kind: str, rows: np.ndarray,
-               rids=None) -> jnp.ndarray:
+    def _route(self, kind: str, rows: np.ndarray, **kw) -> jnp.ndarray:
         rows = np.asarray(rows, np.float32)
         n = rows.shape[0]
         bucket = n if self.buckets is None else bucket_for(n, self.buckets)
-        return self.cell(kind, bucket, rows.shape[1:])(rows, rids=rids)
+        return self.cell(kind, bucket, rows.shape[1:])(rows, **kw)
 
-    def coef_fn(self, rows: np.ndarray, rids=None) -> jnp.ndarray:
+    def coef_fn(self, rows: np.ndarray, rids=None, slot: int = 0,
+                behind: int = 0) -> jnp.ndarray:
         """Serve a ``(n, bh, bw, C, 64)`` coefficient batch (n need not
-        match any bucket — the covering cell pads).  ``rids`` labels the
-        rows' request ids on the flight-recorder span, nothing more."""
-        return self._route("coefficients", rows, rids=rids)
+        match any bucket — the covering cell pads).  ``slot`` (0 or 1)
+        picks the staging buffer and trace row of a batch in flight;
+        ``rids`` and ``behind`` label the flight-recorder spans (the
+        rows' request ids, the batches still in flight), nothing more."""
+        return self._route("coefficients", rows, rids=rids, slot=slot,
+                           behind=behind)
 
-    def packed_fn(self, rows: np.ndarray, rids=None) -> jnp.ndarray:
+    def packed_fn(self, rows: np.ndarray, rids=None, slot: int = 0,
+                  behind: int = 0) -> jnp.ndarray:
         """Serve a ``(n, bh, bw, C·w_in)`` tile-packed batch."""
-        return self._route("bytes", rows, rids=rids)
+        return self._route("bytes", rows, rids=rids, slot=slot,
+                           behind=behind)
 
 
 class PlanGrid:

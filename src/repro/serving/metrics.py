@@ -192,6 +192,7 @@ class ServeMetrics:
         self._device_wall_s = 0.0
         self._images = 0
         self._slots = 0
+        self._batches_behind = 0
         self._cell_hits: dict[str, int] = {}
         self._compiles_total = 0
         self._compiles_post_warmup = 0
@@ -233,7 +234,8 @@ class ServeMetrics:
                      queue_depth: int | None = None,
                      ingest_s: float | None = None,
                      slots: int | None = None,
-                     cell: str | None = None) -> None:
+                     cell: str | None = None,
+                     behind: bool = False) -> None:
         """One executed batch.  ``wall_s`` is *device* wall (what the QoS
         selector is fed); ``ingest_s``, when given, is the host entropy
         decode wall the ingest thread spent on this batch — kept separate
@@ -243,8 +245,12 @@ class ServeMetrics:
         (the capture bucket) — ``slots - images`` slots were padding, and
         the report's ``padding_fraction`` aggregates that waste.
         ``cell`` names the grid cell that served the batch (per-cell hit
-        counts land in ``grid_cell_hits``)."""
+        counts land in ``grid_cell_hits``).  ``behind`` marks a batch
+        launched while another was still on the device (the report's
+        ``overlap_share``); its ``wall_s`` then starts at that batch's
+        read, so it stays this batch's own time."""
         with self._lock:
+            self._batches_behind += bool(behind)
             t = self._tiers.setdefault(
                 tier, {"batches": 0, "images": 0, "wall_s": 0.0,
                        "max_queue_depth": 0, "slots": 0})
@@ -359,6 +365,7 @@ class ServeMetrics:
             # shed requests never reach record_request, so the miss rate
             # counts them explicitly on both sides of the fraction: a shed
             # request is a missed deadline the scheduler saw coming
+            batches = sum(t["batches"] for t in self._tiers.values())
             missed = self._deadline_misses + self._deadline_shed
             served = self._requests + self._deadline_shed
             out: dict[str, Any] = {
@@ -374,6 +381,10 @@ class ServeMetrics:
                 "deadline_miss_rate": round(missed / max(served, 1), 4),
                 "deadline_shed": self._deadline_shed,
                 "device_wall_s": round(self._device_wall_s, 6),
+                # batches launched behind another / batches
+                "overlap_share": (
+                    round(self._batches_behind / batches, 4)
+                    if batches else None),
                 "ingest_wall_s": round(self._ingest_wall_s, 6),
                 "latency_ms": self._lat.summary(),
                 "per_tier": per_tier,

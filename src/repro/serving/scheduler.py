@@ -30,7 +30,14 @@ runtime object:
   :meth:`warmup` sweeps the whole grid so steady-state serving performs
   zero JIT compiles and pads only to the covering bucket, never to
   ``max_batch``; every trace is counted (``ServeMetrics.record_compile``)
-  and any compile after warmup is reported as ``compiles_post_warmup``.
+  and any compile after warmup is reported as ``compiles_post_warmup``;
+* **two batches in flight** — the worker launches batch N+1 before it
+  reads batch N's logits, so the host's work on one batch (completing,
+  forming, stacking, staging) runs while the device runs the other.  A
+  batch goes behind a running one only when a full ``batch`` of requests
+  is queued (a partial one would cost a whole capture bucket of device
+  time); otherwise the worker finishes the running batch first, so at a
+  trickle every batch runs alone.  Completions stay in FIFO order.
 
 Lifecycle mirrors the ``data.pipeline.prefetch`` contract: the worker
 thread is owned by the scheduler — :meth:`close` (or leaving the
@@ -42,7 +49,9 @@ request with :class:`RequestFailed` (stage ``"codec"``, the
 ``codec.CodecError`` on ``__cause__``) — batch-mates decode and serve
 normally via ``ingest_batch(..., on_error="isolate")``.  An executor
 exception gets one bounded retry, then fails only its batch (stage
-``"executor"``) — the scheduler keeps serving.  Ingest-infrastructure
+``"executor"``) — the scheduler keeps serving; a fault at a batch's
+launch or at its read is retried or failed for that batch alone, and the
+batch launched behind it is answered.  Ingest-infrastructure
 failures fail only the batch being decoded (stage ``"ingest"``); the
 codec's pool supervisor respawns dead workers underneath.  Service-level
 failures feed a :class:`~repro.serving.breaker.CircuitBreaker` that
@@ -76,6 +85,11 @@ __all__ = ["DeadlineExceeded", "RequestFailed", "SchedulerClosed",
            "ServeRequest", "ServiceUnavailable", "BandElasticScheduler"]
 
 KINDS = ("coefficients", "bytes")
+
+#: how often the worker, holding one launched batch and waiting for a
+#: full batch to launch behind it, looks whether the device has finished
+#: (``submit`` wakes it at once; only the device's finish is polled)
+_READY_POLL_S = 0.0005
 
 
 class SchedulerClosed(RuntimeError):
@@ -163,6 +177,32 @@ class ServeRequest:
             return  # already resolved; keep the first outcome
         self._error = err
         self._event.set()
+
+
+class _Flight:
+    """One batch the worker formed: launched on the device (``out``, the
+    unread logits) until :meth:`BandElasticScheduler._finish` reads and
+    completes it.  ``slot`` (0 or 1) is its staging buffer and the trace
+    row of its device spans: two batches in flight overlap, so they take
+    different slots."""
+
+    __slots__ = ("seq", "slot", "reqs", "tier_ix", "depth", "decoded",
+                 "t_take", "attempts", "behind", "out", "t0", "t0s")
+
+    def __init__(self, seq: int, slot: int, reqs: list[ServeRequest],
+                 tier_ix: int, depth: int, decoded):
+        self.seq = seq
+        self.slot = slot
+        self.reqs = reqs
+        self.tier_ix = tier_ix
+        self.depth = depth
+        self.decoded = decoded    # (coef, ingest wall) of a bytes batch
+        self.t_take = 0.0         # tracer clock at the take
+        self.attempts = 0         # failed launches and reads so far
+        self.behind = 0           # batches launched and unread at launch
+        self.out = None
+        self.t0 = 0.0             # monotonic, and tracer clock, at stack
+        self.t0s = 0.0
 
 
 class BandElasticScheduler:
@@ -267,11 +307,13 @@ class BandElasticScheduler:
         self._ingesting = 0          # bytes requests currently decoding
         self._ingest_alive = True
         self._rid = itertools.count()
-        self._in_flight = 0
+        # batches taken from the queues and not yet answered, oldest
+        # first: at most two (one on the device, one launched behind it)
+        self._flights: collections.deque[_Flight] = collections.deque()
+        self._read_end = 0.0      # monotonic end of the last logits read
         self._stop = False
         self._drain = True
         self._error: BaseException | None = None
-        self._batches = 0
         self._images = 0
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="scheduler-worker")
@@ -349,7 +391,8 @@ class BandElasticScheduler:
             queues = {k: len(q) for k, q in self._queues.items()}
             queues["decoded_batches"] = len(self._decoded)
             queues["decoding"] = self._ingesting
-            in_flight = self._in_flight
+            # images of every batch taken and not yet answered
+            in_flight = sum(len(f.reqs) for f in self._flights)
             dead = self._error is not None
         return {
             "breaker": self.breaker.snapshot(),
@@ -411,7 +454,7 @@ class BandElasticScheduler:
         timeout."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._idle:
-            while self._pending_locked() or self._in_flight:
+            while self._pending_locked() or self._flights:
                 if self._error is not None:
                     raise self._error
                 left = (None if deadline is None
@@ -636,6 +679,25 @@ class BandElasticScheduler:
     def _ready_locked(self) -> bool:
         return bool(self._decoded) or bool(self._queues["coefficients"])
 
+    def _head_kind_locked(self) -> str | None:
+        """Kind of the batch the next take forms: the queue whose head
+        request is oldest (FIFO across kinds); None when both are empty."""
+        heads = []
+        if self._decoded:
+            heads.append((self._decoded[0][0][0].rid, "bytes"))
+        if self._queues["coefficients"]:
+            heads.append((self._queues["coefficients"][0].rid,
+                          "coefficients"))
+        return min(heads)[1] if heads else None
+
+    def _full_locked(self) -> bool:
+        """Whether the next take fills a whole ``batch``."""
+        kind = self._head_kind_locked()
+        if kind == "bytes":
+            return len(self._decoded[0][0]) >= self.batch
+        return (kind == "coefficients"
+                and len(self._queues["coefficients"]) >= self.batch)
+
     def _take_batch_locked(self, now: float):
         """Pop the next kind-homogeneous batch, shedding expired requests.
 
@@ -643,15 +705,9 @@ class BandElasticScheduler:
         thread's ``(coef, ingest_wall)`` for a bytes batch, None for a
         coefficients batch; ``shed`` are expired requests to fail.
         """
-        heads = []
-        if self._decoded:
-            heads.append((self._decoded[0][0][0].rid, "bytes"))
-        if self._queues["coefficients"]:
-            heads.append((self._queues["coefficients"][0].rid,
-                          "coefficients"))
-        if not heads:
+        kind = self._head_kind_locked()
+        if kind is None:
             return [], None, []
-        _, kind = min(heads)  # oldest head request wins (FIFO across kinds)
         if kind == "bytes":
             reqs, coef, wall = self._decoded.popleft()
             live = [i for i, r in enumerate(reqs)
@@ -671,6 +727,13 @@ class BandElasticScheduler:
                 reqs.append(r)
         return reqs, None, shed
 
+    def _put_back_locked(self, reqs: list[ServeRequest], decoded) -> None:
+        """Return a taken batch to the head of its queue."""
+        if decoded is None:
+            self._queues["coefficients"].extendleft(reversed(reqs))
+        else:
+            self._decoded.appendleft((reqs, *decoded))
+
     def _head_slack_locked(self, now: float) -> float | None:
         slacks = [q[0].deadline - now for q in self._queues.values()
                   if q and q[0].deadline is not None]
@@ -678,143 +741,209 @@ class BandElasticScheduler:
                    for r in e[0][:1] if r.deadline is not None]
         return min(slacks) if slacks else None
 
+    def _turn_locked(self) -> bool | None:
+        """Wait for the worker's next step: True — form and launch a
+        batch; False — read and complete the oldest batch in flight;
+        None — stop.
+
+        With no batch in flight, whatever is queued launches.  With one,
+        a batch launches behind it only once a full ``batch`` is queued;
+        until then the worker waits, and finishes the running batch as
+        soon as the device is done with it.  With two, the older one is
+        finished first."""
+        while True:
+            if self._error is not None:
+                raise self._error
+            if self._stop and not self._drain:
+                return None
+            n = len(self._flights)
+            if n > 1:
+                return False
+            if n:
+                if self._full_locked():
+                    return True
+                if _device_done(self._flights[0].out):
+                    return False
+            elif self._ready_locked():
+                return True
+            elif self._stop and not self._pending_locked():
+                return None
+            self._work.wait(timeout=_READY_POLL_S if n else 0.05)
+
     def _run(self) -> None:
         tr = self.tracer
         try:
             while True:
                 with self._lock:
-                    while (not self._ready_locked() and not self._stop
-                           and self._error is None):
-                        self._work.wait(timeout=0.05)
-                    if self._error is not None:
-                        raise self._error
-                    if self._stop and (not self._drain
-                                       or (not self._pending_locked()
-                                           and not self._ingesting)):
+                    step = self._turn_locked()
+                    if step is None:
                         break
-                    now = time.monotonic()
-                    # batch-form: from the take to the dispatch loop
-                    form = tr.scope("scheduler", "batch-form")
-                    form.__enter__()
-                    slack = self._head_slack_locked(now)
-                    depth = self._pending_locked()
-                    reqs, decoded, shed = self._take_batch_locked(now)
-                    tier_ix = None
-                    if reqs:
-                        # tier selection happens *after* the take so the
-                        # capture bucket is known and the QoS estimates
-                        # key to the right grid cell (a bucket-1 trickle
-                        # must not be judged by bucket-8 latency)
-                        tier_ix = self.selector.select(
-                            pending=depth, batch=self.batch,
-                            head_slack_s=slack,
-                            bucket=self.grid_engine.bucket_for(len(reqs)))
-                    self._in_flight = len(reqs)
+                    if not step:
+                        head = self._flights[0]
+                    else:
+                        now = time.monotonic()
+                        # batch-form: from the take to the launch
+                        form = tr.scope("scheduler", "batch-form")
+                        form.__enter__()
+                        slack = self._head_slack_locked(now)
+                        depth = self._pending_locked()
+                        reqs, decoded, shed = self._take_batch_locked(now)
+                        if reqs and self._flights and len(reqs) < self.batch:
+                            # shedding left a partial batch: it waits
+                            # until it can run alone
+                            self._put_back_locked(reqs, decoded)
+                            reqs = []
+                        f = None
+                        if reqs:
+                            # tier selection happens *after* the take so
+                            # the capture bucket is known and the QoS
+                            # estimates key to the right grid cell (a
+                            # bucket-1 trickle must not be judged by
+                            # bucket-8 latency)
+                            tier_ix = self.selector.select(
+                                pending=depth, batch=self.batch,
+                                head_slack_s=slack,
+                                bucket=self.grid_engine.bucket_for(
+                                    len(reqs)))
+                            # the slot the batch in flight leaves free
+                            slot = (1 - self._flights[0].slot
+                                    if self._flights else 0)
+                            f = _Flight(self._dispatch_seq, slot, reqs,
+                                        tier_ix, depth, decoded)
+                            self._dispatch_seq += 1
+                            self._flights.append(f)
+                if not step:
+                    self._finish(head)
+                    continue
                 self._shed(shed)
-                if not reqs:
+                if f is None:
                     if tr.enabled:
                         form.args = {"n": 0, "shed": len(shed)}
                     form.__exit__(None, None, None)
                     with self._idle:
-                        self._in_flight = 0
                         self._idle.notify_all()
                     continue
-                t_take = tr.now() if tr.enabled else 0.0
                 if tr.enabled:
                     # queue span closes here for the whole batch — once,
-                    # before the retry loop, so retries don't duplicate it
+                    # before any retry, so retries don't duplicate it
+                    f.t_take = tr.now()
                     for r in reqs:
-                        tr.span("request", "queue", r.t_enq, t_take,
+                        tr.span("request", "queue", r.t_enq, f.t_take,
                                 tid=r.rid)
-                    bucket = self.grid_engine.bucket_for(len(reqs))
-                    form.args = {"tier": self.tier_names[tier_ix],
-                                 "n": len(reqs), "bucket": bucket,
+                    form.args = {"tier": self.tier_names[f.tier_ix],
+                                 "n": len(reqs),
+                                 "bucket": self.grid_engine.bucket_for(
+                                     len(reqs)),
                                  "kind": reqs[0].kind}
-                seq = self._dispatch_seq
-                self._dispatch_seq += 1
                 form.__exit__(None, None, None)
-                err: Exception | None = None
-                for _attempt in range(self.executor_retries + 1):
-                    try:
-                        if self.faults is not None:
-                            self.faults.on_execute(seq, reqs)
-                        self._execute(reqs, tier_ix, depth, decoded,
-                                      t_take=t_take)
-                        err = None
-                        break
-                    except Exception as e:  # transient? bounded retry
-                        err = e
-                    except BaseException as e:
-                        for r in reqs:  # the in-flight batch left the
-                            r._fail(e)  # queue — _fail_all can't see it
-                        raise
-                if err is None:
-                    self.breaker.record_success()
-                else:
-                    # retry budget exhausted: fail only this batch — the
-                    # scheduler survives, the breaker accumulates
-                    if tr.enabled:
-                        t = tr.now()
-                        for r in reqs:
-                            tr.instant("request", "fail", t=t, tid=r.rid,
-                                       args={"stage": "executor"})
-                    for r in reqs:
-                        r._fail(RequestFailed("executor", r.rid, err))
-                    self.metrics.record_failure("executor", len(reqs))
-                    self.breaker.record_failure("executor")
-                    self.selector.note_failure()
-                    with self._idle:
-                        self._in_flight = 0
-                        self._idle.notify_all()
+                self._launch(f)
         except BaseException as e:  # noqa: BLE001 — re-raised at waiters
             self._fail_all(e)
             return
         self._fail_all(SchedulerClosed("scheduler closed before completion"),
                        record=False)
 
-    def _execute(self, reqs: list[ServeRequest], tier_ix: int,
-                 depth: int, decoded=None, t_take: float = 0.0) -> None:
-        ex = self._execs[tier_ix]
-        name = self.tier_names[tier_ix]
-        n = len(reqs)
-        bucket = self.grid_engine.bucket_for(n)
+    def _launch(self, f: _Flight) -> bool:
+        """Stage and launch ``f`` behind whatever is in flight, retrying
+        under ``executor_retries``; False when the batch failed."""
+        while True:
+            f.behind = len(self._flights) - 1
+            try:
+                if self.faults is not None:
+                    self.faults.on_execute(f.seq, f.reqs)
+                self._execute(f)
+                return True
+            except Exception as e:  # transient? bounded retry
+                if not self._retry(f, e):
+                    return False
+
+    def _retry(self, f: _Flight, err: Exception) -> bool:
+        """Count a failed launch or read of ``f``: True while the retry
+        budget lasts; past it, fail only this batch — the scheduler
+        survives, the breaker accumulates — and return False."""
+        f.attempts += 1
+        if f.attempts <= self.executor_retries:
+            return True
         tr = self.tracer
-        rids = [r.rid for r in reqs] if tr.enabled else None
-        # rids ride along only when tracing: untraced dispatch keeps the
-        # bare executor signature (tests monkeypatch coef_fn/packed_fn)
-        kw = {"rids": rids} if tr.enabled else {}
-        ingest_wall = None
-        t0 = time.monotonic()
-        t0s = tr.now() if tr.enabled else 0.0
-        if reqs[0].kind == "bytes":
+        if tr.enabled:
+            t = tr.now()
+            for r in f.reqs:
+                tr.instant("request", "fail", t=t, tid=r.rid,
+                           args={"stage": "executor"})
+        for r in f.reqs:
+            r._fail(RequestFailed("executor", r.rid, err))
+        self.metrics.record_failure("executor", len(f.reqs))
+        self.breaker.record_failure("executor")
+        self.selector.note_failure()
+        with self._idle:
+            self._drop_locked(f)
+            self._idle.notify_all()
+        return False
+
+    def _drop_locked(self, f: _Flight) -> None:
+        if f in self._flights:  # _fail_all may have cleared it
+            self._flights.remove(f)
+
+    def _execute(self, f: _Flight) -> None:
+        """Stage batch ``f`` and launch it.  The launch is asynchronous:
+        ``f.out`` holds logits the device may still be computing."""
+        ex = self._execs[f.tier_ix]
+        tr = self.tracer
+        # the slot picks the staging buffer the other batch in flight
+        # is not using; rids and depth label the spans when tracing
+        kw = {"slot": f.slot}
+        if tr.enabled:
+            kw.update(rids=[r.rid for r in f.reqs], behind=f.behind)
+        f.t0 = time.monotonic()
+        f.t0s = tr.now() if tr.enabled else 0.0
+        if f.reqs[0].kind == "bytes":
             from repro.codec import ingest as ingestlib
 
             # decode already happened on the ingest thread; only the
             # pack-to-tier-width slice and the device walk run here.
             # Rows go in *unpadded*: the grid cell stages them into its
             # pinned bucket-shaped buffer and zero-fills the pad tail.
-            coef, ingest_wall = decoded
-            kind = "bytes"
-            with tr.scope("device", "stack"):
-                rows = ingestlib.pack_tiles(coef, ex.w_in)
-            out = ex.packed_fn(rows, **kw)
+            with tr.scope("device", "stack", tid=f.slot):
+                rows = ingestlib.pack_tiles(f.decoded[0], ex.w_in)
+            f.out = ex.packed_fn(rows, **kw)
         else:
-            kind = "coefficients"
-            with tr.scope("device", "stack"):
+            with tr.scope("device", "stack", tid=f.slot):
                 rows = np.stack([np.asarray(r.payload, np.float32)
-                                 for r in reqs])
-            out = ex.coef_fn(rows, **kw)
-        with tr.scope("device", "read"):
-            logits = np.asarray(out)
-        wall = time.monotonic() - t0
+                                 for r in f.reqs])
+            f.out = ex.coef_fn(rows, **kw)
+
+    def _finish(self, f: _Flight) -> None:
+        """Read ``f``'s logits, waiting for the device, and complete its
+        requests.  A failed read launches ``f`` again from its requests,
+        under the retry budget a failed launch draws on."""
+        tr = self.tracer
+        while True:
+            try:
+                with tr.scope("device", "read", tid=f.slot):
+                    logits = np.asarray(f.out)
+                break
+            except Exception as e:
+                if not (self._retry(f, e) and self._launch(f)):
+                    return
+        t_end = time.monotonic()
         t1s = tr.now() if tr.enabled else 0.0
+        # the batch's own wall, for the QoS EMA and the device wall: from
+        # its stack, or, when it was launched behind the batch before,
+        # from that batch's read, to its own read
+        wall = t_end - max(f.t0, self._read_end)
+        self._read_end = t_end
+        name = self.tier_names[f.tier_ix]
+        kind = f.reqs[0].kind
+        n = len(f.reqs)
+        bucket = self.grid_engine.bucket_for(n)
         with tr.scope("scheduler", "complete",
                       args={"n": n} if tr.enabled else None):
             if tr.enabled:
-                # device-dispatch is exactly the interval the report's
-                # device_wall_s accumulates, so span sums reconcile
+                # device-dispatch runs from the stack to the logits on
+                # the host; two in flight overlap, on rows 0 and 1, and
+                # the union of the spans is what device_wall_s sums
                 dargs = {"tier": name, "n": n, "bucket": bucket,
-                         "kind": kind, "rids": rids}
+                         "kind": kind, "rids": [r.rid for r in f.reqs]}
                 # --profile-grid cost annotations: the span carries the
                 # cell's static FLOPs and roofline-predicted wall, so a
                 # Perfetto query can put predicted-vs-measured on one
@@ -824,21 +953,24 @@ class BandElasticScheduler:
                     dargs.update({k: cost[k] for k in ("flops",
                                                        "predicted_us")
                                   if k in cost})
-                tr.span("device", "device-dispatch", t0s, t1s, args=dargs)
-                for r in reqs:
+                tr.span("device", "device-dispatch", f.t0s, t1s,
+                        tid=f.slot, args=dargs)
+                for r in f.reqs:
                     # flow arrow: this request's queue row -> its batch
-                    tr.flow(r.rid, ("request", r.rid, t_take),
-                            ("device", 0, t0s))
+                    tr.flow(r.rid, ("request", r.rid, f.t_take),
+                            ("device", f.slot, f.t0s))
             # only device wall reaches the QoS EMA: host decode cost is
             # band-independent, so folding it in would poison tier
             # selection
-            self.selector.observe(tier_ix, wall, bucket=bucket)
-            self.metrics.record_batch(name, n, wall, queue_depth=depth,
-                                      ingest_s=ingest_wall, slots=bucket,
-                                      cell=f"{name}/{kind}/b{bucket}")
+            self.selector.observe(f.tier_ix, wall, bucket=bucket)
+            self.metrics.record_batch(
+                name, n, wall, queue_depth=f.depth,
+                ingest_s=None if f.decoded is None else f.decoded[1],
+                slots=bucket, cell=f"{name}/{kind}/b{bucket}",
+                behind=f.behind > 0)
             now = time.monotonic()
             t_now = tr.now() if tr.enabled else 0.0
-            for i, r in enumerate(reqs):
+            for i, r in enumerate(f.reqs):
                 r._complete(logits[i], name)
                 if tr.enabled:
                     tr.instant("request", "complete", t=t_now, tid=r.rid,
@@ -847,9 +979,9 @@ class BandElasticScheduler:
                     r.latency_s, tier=name,
                     deadline_missed=(r.deadline is not None
                                      and now > r.deadline))
+        self.breaker.record_success()
         with self._idle:
-            self._in_flight = 0
-            self._batches += 1
+            self._drop_locked(f)
             self._images += n
             self._idle.notify_all()
 
@@ -859,11 +991,23 @@ class BandElasticScheduler:
                 self._error = err
             pending = [r for q in self._queues.values() for r in q]
             pending += [r for e in self._decoded for r in e[0]]
+            # the batches in flight left the queues: fail them here too
+            pending += [r for f in self._flights for r in f.reqs]
             for q in self._queues.values():
                 q.clear()
             self._decoded.clear()
-            self._in_flight = 0
+            self._flights.clear()
             self._work.notify_all()
             self._idle.notify_all()
         for r in pending:
             r._fail(err)
+
+
+def _device_done(out) -> bool:
+    """Whether a launched batch's logits are computed.  An output that
+    cannot say (its launch failed on the device) counts as done: the
+    read then raises, for that batch alone."""
+    try:
+        return out.is_ready()
+    except Exception:
+        return True

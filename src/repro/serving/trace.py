@@ -17,13 +17,18 @@ track (pid)     name             interval
                                  per-request completion (``args.n``)
 ``ingest``      ingest-decode    one bytes batch through ``codec.ingest_batch``
 ``ingest``      decode-shard     one spawn-pool shard of that batch (tid = shard)
-``device``      device-dispatch  staged batch through the grid cell executable
-                                 (the interval ``device_wall_s`` accumulates)
+``device``      device-dispatch  staged batch through the grid cell executable,
+                                 stack to logits on the host (tid = the
+                                 batch's row, 0 or 1: two batches in flight
+                                 overlap; the spans' union is the time
+                                 ``device_wall_s`` accumulates)
 ``device``      stack            (nested) ``np.stack`` of the coefficient
                                  payloads, or ``pack_tiles`` of a bytes batch
 ``device``      pad/stage        (nested) staging copy into the pinned bucket
                                  buffer and the host→device copy
 ``device``      launch           (nested) the captured executable's call
+                                 (``args.behind``: batches launched and not
+                                 yet read when it launched)
 ``device``      read             (nested) ``np.asarray`` of the logits: waits
                                  for the device, copies to the host
 ``request``     admission/queue  per-request rows (tid = request id)
@@ -317,8 +322,10 @@ def validate_trace(obj: dict, *, require_closed: bool = True) -> dict:
     (``complete``/``fail``/``shed``) is an orphan and fails validation.
 
     Returns a summary dict: event counts, per-terminal request counts,
-    ``device_span_s``/``ingest_span_s`` (span sums that must reconcile
-    with the report's ``device_wall_s``/``ingest_wall_s``), and
+    ``device_span_s`` (the time ``device-dispatch`` spans cover: their
+    union, since two batches in flight overlap) and ``ingest_span_s``
+    (the ``ingest-decode`` spans' sum), which must reconcile with the
+    report's ``device_wall_s``/``ingest_wall_s``, and
     ``open_chains``.  Raises :class:`ValueError` on any violation.
     """
     problems: list[str] = []
@@ -343,6 +350,7 @@ def validate_trace(obj: dict, *, require_closed: bool = True) -> dict:
     queued: set[int] = set()
     terminal: dict[int, str] = {}
     dispatch_members: dict[int, int] = {}  # rid -> device-dispatch count
+    dispatches: list[tuple[float, float]] = []
     n_spans = n_instants = n_flows = 0
     for k, ev in enumerate(evs):
         if not isinstance(ev, dict):
@@ -380,6 +388,7 @@ def validate_trace(obj: dict, *, require_closed: bool = True) -> dict:
                 elif name == "queue":
                     queued.add(rid)
             elif track == "device" and name == "device-dispatch":
+                dispatches.append((ev["ts"], ev["ts"] + dur))
                 for rid in (ev.get("args") or {}).get("rids", ()):
                     dispatch_members[rid] = dispatch_members.get(rid, 0) + 1
         elif ph == "i":
@@ -422,9 +431,19 @@ def validate_trace(obj: dict, *, require_closed: bool = True) -> dict:
         "shed": sum(1 for t in terminal.values() if t == "shed"),
         "open_chains": open_chains,
         "spans_by_name": spans_by_name,
-        "device_span_s": span_sum_s.get("device/device-dispatch", 0.0),
+        "device_span_s": _union_us(dispatches) / 1e6,
         "ingest_span_s": span_sum_s.get("ingest/ingest-decode", 0.0),
     }
+
+
+def _union_us(intervals: list[tuple[float, float]]) -> float:
+    """Length of the union of ``(start, end)`` intervals."""
+    total, end = 0.0, -math.inf
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
 
 
 # ---------------------------------------------------------------------------

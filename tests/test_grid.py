@@ -221,6 +221,31 @@ def test_cell_staging_buffer_survives_donation(setup):
     assert len(g.pool) == 1
 
 
+def test_slots_stage_into_their_own_buffers(setup):
+    """The batch launched behind a running one (slot 1) stages into its
+    own buffer: slot 0's staged rows stay as they were, in case the
+    host->device copy of the running batch still reads them."""
+    spec, coef, plan = setup
+    ladder = SV.build_ladder(plan, caps=(None,))
+    g = SV.PlanGrid(ladder, batch=2, grid=tuple(coef.shape[1:3]),
+                    channels=int(coef.shape[3]), executor=EXECUTOR)
+    col = g.columns[0]
+    a = np.asarray(coef[0:2], np.float32)
+    b = np.asarray(coef[2:4], np.float32)
+    out_a = col.coef_fn(a, slot=0)
+    out_b = col.coef_fn(b, slot=1)
+    shape = (2, *a.shape[1:])
+    np.testing.assert_array_equal(g.pool.get(shape, slot=0), a)
+    np.testing.assert_array_equal(g.pool.get(shape, slot=1), b)
+    cp = ladder.tiers[0].compiled
+    for rows, out in ((a, out_a), (b, out_b)):
+        want = np.asarray(PL.apply_compiled(cp, jnp.asarray(rows),
+                                            executor=EXECUTOR))
+        np.testing.assert_allclose(np.asarray(out), want, atol=1e-5)
+    assert len(g.pool) == 1
+    assert g.pool.nbytes == 2 * a.nbytes
+
+
 def test_cell_rejects_oversized_or_foreign_rows(setup):
     spec, coef, plan = setup
     ladder = SV.build_ladder(plan, caps=(None,))

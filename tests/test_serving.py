@@ -226,7 +226,7 @@ def test_scheduler_worker_exception_contained(setup):
 
     calls = []
 
-    def bad_fn(_):
+    def bad_fn(_, **kw):
         calls.append(1)
         raise boom
 
@@ -258,9 +258,9 @@ def test_scheduler_admission_control(setup):
     for ex in {id(e): e for e in s._execs}.values():
         inner = ex.coef_fn
 
-        def gated(c, _inner=inner):
+        def gated(c, _inner=inner, **kw):
             gate.wait(timeout=60)  # hold the worker mid-batch
-            return _inner(c)
+            return _inner(c, **kw)
 
         ex.coef_fn = gated
     results = [s.submit(np.asarray(coef[0])) for _ in range(8)]

@@ -268,6 +268,19 @@ def test_validate_accepts_closed_chains():
     assert summ["device_span_s"] == pytest.approx(0.6, rel=1e-3)
 
 
+def test_validate_device_span_is_union_of_dispatches():
+    """Two batches in flight overlap: the dispatch spans' union, not
+    their sum, is the time ``device_wall_s`` accumulates."""
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    t0 = clk.tick()
+    tr.span("device", "device-dispatch", t0, t0 + 0.3, tid=0)
+    tr.span("device", "device-dispatch", t0 + 0.2, t0 + 0.5, tid=1)
+    tr.span("device", "device-dispatch", t0 + 0.7, t0 + 0.8, tid=0)
+    summ = validate_trace(tr.export())
+    assert summ["device_span_s"] == pytest.approx(0.6, rel=1e-3)
+
+
 def test_validate_rejects_orphan_chain():
     clk = FakeClock()
     tr = Tracer(clock=clk)
@@ -478,7 +491,8 @@ def _host_events(log_dir):
 def test_per_batch_scopes_reach_the_profiler(setup, tmp_path):
     """Under a profiler session every dispatched batch leaves each
     per-batch span once in the ring and once as a host-plane event; the
-    four device children nest in their device-dispatch span; the trace
+    four device children nest in their device-dispatch span on their
+    trace row (two batches in flight overlap, on rows 0 and 1); the trace
     still validates; the disabled tracer's scope leaves no event."""
     spec, coef, plan, ladder = setup
     tracer = SV.Tracer()
@@ -500,16 +514,17 @@ def test_per_batch_scopes_reach_the_profiler(setup, tmp_path):
             jax.profiler.stop_trace()
     host = _host_events(tmp_path)
     spans = [e for e in tracer.events() if e[0] == "X"]
-    dispatches = [(t, t + d) for _, track, _, name, t, d, _ in spans
+    dispatches = [(tid, t, t + d) for _, track, tid, name, t, d, _ in spans
                   if (track, name) == ("device", "device-dispatch")]
     assert len(dispatches) >= 5  # batch 2: 6 coefficient + 4 bytes images
     for full in PER_BATCH:
         ring = [e for e in spans if f"{e[1]}/{e[3]}" == full]
         assert len(ring) == len(dispatches), full
         assert len(host.get(full, [])) == len(dispatches), full
-    for _, track, _, name, t, d, _ in spans:
+    for _, track, tid, name, t, d, _ in spans:
         if f"{track}/{name}" in DEVICE_CHILDREN:
-            assert sum(a <= t and t + d <= b for a, b in dispatches) == 1
+            assert sum(row == tid and a <= t and t + d <= b
+                       for row, a, b in dispatches) == 1
     assert "device/null-probe" not in host
     ingest = [e for e in spans if (e[1], e[3]) == ("ingest", "ingest-decode")]
     assert len(host["ingest/ingest-decode"]) == len(ingest) >= 1
